@@ -149,6 +149,8 @@ def main(argv=None) -> int:
         for name, fn in BENCHES.items():
             print(f"{name:<14s} {(fn.__doc__ or '').strip()}")
         return 0
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     scale = (BenchScale(n_cols=65536, n_cols_arith=4096, full=True)
              if args.full else BenchScale())
 

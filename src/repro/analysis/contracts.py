@@ -15,9 +15,12 @@ logical/placed) derive at trace time and verify the invariants they assume:
     window block has capacity for its logical block, and (when values are
     available) every ``col_ids`` entry lands statically inside its block's
     window slice;
+  * **folded weight bits** — ``mode="folded"`` feeds the MXU an int8
+    folded weight (<= 2^WB - 1), so WB <= 7;
   * **VMEM budget** — the per-grid-step footprint derived from the
-    BlockSpecs (streamed blocks double-buffered + compute transients) stays
-    under :data:`VMEM_BUDGET_BYTES`.  This is the check that outlaws the
+    BlockSpecs (streamed blocks double-buffered + compute transients: the
+    int32 unpack/fold stages, the int8 MXU operands, the placed one-hot
+    column selection) stays under :data:`VMEM_BUDGET_BYTES`.  This is the check that outlaws the
     pre-block-alignment "whole window per K-tile" layout.
 
 Violations raise :class:`ContractViolation` naming the kernel, the failed
@@ -244,6 +247,11 @@ def plan_kernel(call: KernelCall) -> TilePlan:
             call.kernel, "shape",
             f"non-positive dimension in B={call.b} K={call.k} N={call.n} "
             f"WB={call.wb}")
+    if call.mode == "folded" and call.wb > 7:
+        raise ContractViolation(
+            call.kernel, "folded-weight-bits",
+            f"folded mode needs WB <= 7 (an int8 MXU operand), got "
+            f"{call.wb}")
     plane_kb, x_kb, k_steps = _k_plan(call)
     nb, block_cols, pwb, n_pad = _n_plan(call)
 
@@ -284,14 +292,22 @@ def plan_kernel(call: KernelCall) -> TilePlan:
     if call.placed:
         vmem["col_ids"] = 4 * nb
     transient = 0
+    if call.placed:
+        # one-hot column selection on the MXU: words select in bf16 with
+        # an f32 result, dense 0/1 planes in int8 with an int32 result
+        sel = 2 if call.layout == "bitpack8" else 1
+        transient += sel * pwb * nb                   # selection matrix
+        transient += sel * call.wb * plane_kb * pwb   # window operand copy
+        transient += 4 * call.wb * plane_kb * nb      # selected columns
     if call.layout == "bitpack8":
-        transient += call.wb * x_kb * nb              # in-VMEM unpacked tile
+        transient += 4 * call.wb * x_kb * nb          # int32 shift-mask stage
+        transient += call.wb * x_kb * nb              # int8 unpacked tile
     if call.mode == "folded":
-        transient += 4 * x_kb * nb                    # folded int32 weights
+        transient += 4 * x_kb * nb                    # int32 fold
+        transient += x_kb * nb                        # int8 MXU operand
     else:
         transient += 4 * bb * nb                      # shifted plane partial
-    if transient:
-        vmem["transient"] = transient
+    vmem["transient"] = transient
 
     plan = TilePlan(kernel=call.kernel, grid=grid, bb=bb, nb=nb, x_kb=x_kb,
                     plane_kb=plane_kb, block_cols=block_cols,
@@ -593,6 +609,9 @@ def adversarial_fixtures() -> list[tuple[str, str, KernelCall,
         ("unknown-layout", "layout",
          KernelCall(entry="gemv", b=1, k=64, n=64, layout="bitpack4"),
          None),
+        ("folded-eight-bit", "folded-weight-bits",
+         KernelCall(entry="gemm", b=8, k=256, n=256, wb=8, mode="folded"),
+         None),
     ]
 
 
@@ -639,9 +658,11 @@ DOC_END = "<!-- END GENERATED: vmem-budget -->"
 
 #: Reference operating point of the documented table: serving decode with
 #: a full MXU-aligned tile (Kb = Nb = 256) and the placed example at the
-#: ~3 % ECR window stride the placement benchmark measures.
+#: ~3 % ECR span the placement benchmark measures (264 columns), rounded
+#: up to the 128-lane multiple the placer emits.
 _DOC_REF = dict(b=8, k=2048, n=2048, wb=4)
-_DOC_PLACED_WINDOW_BLOCK = 264
+_DOC_PLACED_SPAN = 264
+_DOC_PLACED_WINDOW_BLOCK = -(-_DOC_PLACED_SPAN // 128) * 128
 
 
 def _kib(nbytes: int) -> str:
@@ -672,7 +693,9 @@ def render_vmem_table() -> str:
         f"Derived from `analysis/contracts.py` at B = {ref['b']}, "
         f"WB = {ref['wb']}, Kb = Nb = 256 (K = N = {ref['k']}); the placed "
         "column streams one window block of "
-        f"`window_block = {_DOC_PLACED_WINDOW_BLOCK}` (≈ 3 % ECR span):",
+        f"`window_block = {_DOC_PLACED_WINDOW_BLOCK}` (the ≈ 3 % ECR span "
+        f"of {_DOC_PLACED_SPAN} columns rounded up to a multiple of 128 "
+        "lanes):",
         "",
         "| per-grid-step block | dense (legacy) | bit-packed "
         "| bit-packed placed |",
@@ -689,7 +712,8 @@ def render_vmem_table() -> str:
         f" | **{_kib(pl.streamed_bytes + pl.vmem['out'])}** |",
         "",
         "Budget check: double-buffered streaming plus compute transients "
-        "(folded int32 weight tile, bit-unpack scratch) must stay under "
+        "(int32 bit-unpack and fold stages, int8 MXU operands, the placed "
+        "one-hot column selection) must stay under "
         f"**{VMEM_BUDGET_BYTES // (1024 * 1024)} MiB** per step "
         "(`contracts.VMEM_BUDGET_BYTES`) — totals here: "
         f"dense {_kib(d.vmem_total_bytes)}, "

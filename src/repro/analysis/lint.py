@@ -26,9 +26,9 @@ Rule catalog (docs/analysis.md mirrors this):
                               library code produce hidden cross-call
                               correlation; thread keys (or derive them from
                               config seeds) instead.
-  no-removed-jax-api          APIs removed from the pinned jax
-                              (``jax.set_mesh``) — use the portable
-                              ``launch/mesh.use_mesh`` shim.
+  no-removed-jax-api          mesh-context APIs (``jax.set_mesh``) are
+                              entered only through
+                              ``launch/mesh.use_mesh``.
   no-recal-on-decode-path     ladder identification (Algorithm 1) is
                               minutes of work and must never run inside
                               the decode loop — the decode path
@@ -60,7 +60,8 @@ import os
 #: Kernel implementation modules private to the kernels package.
 KERNEL_MODULES = frozenset({"bitplane_gemv", "bitplane_gemm", "majx"})
 
-#: jax attributes removed on the pinned jaxlib (rule: no-removed-jax-api).
+#: jax attributes reached only through launch/mesh.py (rule:
+#: no-removed-jax-api).
 REMOVED_JAX_APIS = frozenset({"set_mesh"})
 
 #: Fleet recalibration entrypoints (rule: no-recal-on-decode-path).
@@ -242,10 +243,10 @@ def _check_prng(tree: ast.AST, path: str):
 
 
 @rule("no-removed-jax-api",
-      "references to APIs removed on the pinned jax (use launch/mesh shims)")
+      "mesh-context APIs outside launch/mesh (use launch/mesh.use_mesh)")
 def _check_removed_apis(tree: ast.AST, path: str):
     if _norm(path).endswith("repro/launch/mesh.py"):
-        return  # the one portability shim allowed to probe the old API
+        return  # the one module that installs mesh contexts
     for node in ast.walk(tree):
         if (isinstance(node, ast.Attribute)
                 and node.attr in REMOVED_JAX_APIS
@@ -253,7 +254,7 @@ def _check_removed_apis(tree: ast.AST, path: str):
                 and node.value.id == "jax"):
             yield Finding(
                 "no-removed-jax-api", path, node.lineno,
-                f"jax.{node.attr} was removed on the pinned jax — use "
+                f"jax.{node.attr} is entered through "
                 "repro.launch.mesh.use_mesh")
 
 

@@ -14,9 +14,10 @@ call:
         Algorithm 1 (bit-identical to N independent ``identify_calibration``
         calls — the equivalence oracle);
       - ``reference``:    vmapped pure-jnp fused iteration (kernels/ref.py);
-      - ``fused``:        vmapped Pallas kernel (kernels/majx.calib_iter_fused)
-        that does SiMRA sensing + bias accumulation + ladder level-step in a
-        single pass instead of three jitted stages.
+      - ``fused``:        Pallas kernel (kernels/majx.calib_iter_fused), the
+        subarray axis part of its grid, that does SiMRA sensing + bias
+        accumulation + ladder level-step in a single pass instead of three
+        jitted stages.
     With a ``mesh``, the subarray axis is ``shard_map``-ped over every mesh
     axis (launch/mesh.py meshes compose directly), one RNG stream per shard.
   * ``fleet_calib_charges``    — levels -> per-subarray calibration-row
@@ -33,7 +34,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -104,31 +104,47 @@ def manufacture_fleet(
     return jax.vmap(one)(jnp.arange(cfg.n_subarrays_total))
 
 
-def ladder_tables(
-    ladder: OffsetLadder, params: PhysicsParams
-) -> tuple[tuple[float, ...], tuple[float, ...]]:
-    """Static per-level (charge sum, swing^2 sum) of the calibration rows."""
+def sense_tables(
+    ladder: OffsetLadder, params: PhysicsParams,
+    config: CalibrationConfig = CalibrationConfig(),
+) -> tuple[tuple[tuple[float, ...], ...], tuple[float, ...]]:
+    """Static sensing tables of one calibration MAJX: (bitline voltage
+    [level][ones among the operands], noise sigma [level]).
+
+    Operand bits are 0/1, so a sensing's charge sum is ones + the level's
+    calibration-row sum + the constant rows, and its swing^2 sum is the
+    operand count + the level's calibration-row swing + the constant rows.
+    Both calibration engines read these tables, so neither recomputes the
+    physics in its own float order.
+    """
     rc = ladder.row_charges(params)                        # [L, n_rows]
-    qsum = tuple(float(x) for x in rc.sum(axis=1))
-    swing = tuple(float(x) for x in ((2.0 * (rc - NEUTRAL)) ** 2).sum(axis=1))
-    return qsum, swing
+    qsum = rc.sum(axis=1)
+    swing = ((2.0 * (rc - NEUTRAL)) ** 2).sum(axis=1)
+    ones = np.arange(config.maj_inputs + 1, dtype=np.float32)
+    charge = ones[None, :] + qsum[:, None] + config.const_charge_sum
+    v = np.asarray(params.bitline_voltage(charge, params.n_simra_rows),
+                   np.float32)
+    sigma = np.asarray(params.sensing_sigma(
+        np.float32(ladder.n_fracs),
+        swing + config.maj_inputs + config.const_swing_sq), np.float32)
+    return (tuple(tuple(float(x) for x in row) for row in v),
+            tuple(float(x) for x in sigma))
 
 
 def _block_calibrate(ladder: OffsetLadder, params: PhysicsParams,
-                     config: CalibrationConfig, method: str, interpret: bool):
+                     config: CalibrationConfig, method: str):
     """Returns f(key, offsets [Gl, C]) -> (levels [Gl, C], |bias| history)."""
-    qsum, swing = ladder_tables(ladder, params)
+    level_v, level_sigma = sense_tables(ladder, params, config)
 
-    def one_iter(inputs, noise, levels, offs):
-        if method == "fused":
+    def block_iter(inputs, noise, levels, offs):
+        """One iteration over the block's [Gl, ...] operands."""
+        if method == "fused":   # the subarray axis is part of the grid
             return calib_iter_fused(
-                inputs, noise, levels, offs, params, ladder.n_fracs,
-                qsum, swing, config.threshold, config.maj_inputs,
-                config.const_charge_sum, config.const_swing_sq, interpret)
-        return calib_iter_ref(
-            inputs, noise, levels, offs, params, ladder.n_fracs,
-            qsum, swing, config.threshold, config.maj_inputs,
-            config.const_charge_sum, config.const_swing_sq)
+                inputs, noise, levels, offs, level_v, level_sigma,
+                config.threshold, config.maj_inputs)
+        return jax.vmap(lambda i, n, lv, o: calib_iter_ref(
+            i, n, lv, o, level_v, level_sigma, config.threshold,
+            config.maj_inputs))(inputs, noise, levels, offs)
 
     def run(key, offs):
         gl, c = offs.shape
@@ -141,7 +157,7 @@ def _block_calibrate(ladder: OffsetLadder, params: PhysicsParams,
             ).astype(jnp.float32)
             noise = jax.random.normal(
                 k_noise, (gl, config.n_samples, c), jnp.float32)
-            new, bias = jax.vmap(one_iter)(inputs, noise, levels, offs)
+            new, bias = block_iter(inputs, noise, levels, offs)
             return new, jnp.abs(bias).mean()
 
         keys = jax.random.split(key, config.n_iterations)
@@ -159,7 +175,6 @@ def calibrate_fleet(
     *,
     mesh: Mesh | None = None,
     method: str = "fused",
-    interpret: bool = True,
 ) -> FleetCalibration:
     """Run Algorithm 1 over the whole subarray grid.
 
@@ -180,7 +195,7 @@ def calibrate_fleet(
             jnp.arange(g), sense_offsets)
         return FleetCalibration(levels, None, cfg, method)
 
-    run = _block_calibrate(ladder, params, config, method, interpret)
+    run = _block_calibrate(ladder, params, config, method)
 
     if mesh is None or mesh.size == 1:
         levels, hist = jax.jit(run)(key, sense_offsets)
@@ -199,9 +214,9 @@ def calibrate_fleet(
         levels, hist = run(jax.random.fold_in(key_block[0], idx), offs)
         return levels, jax.lax.pmean(hist, axes)
 
-    levels, hist = jax.jit(shard_map(
+    levels, hist = jax.jit(jax.shard_map(
         sharded, mesh=mesh, in_specs=(P(), spec), out_specs=(spec, P()),
-        check_rep=False))(key[None], sense_offsets)
+        check_vma=False))(key[None], sense_offsets)
     return FleetCalibration(levels, hist, cfg, method)
 
 
@@ -214,7 +229,6 @@ def recalibrate_subarrays(
     config: CalibrationConfig = CalibrationConfig(),
     *,
     method: str = "reference",
-    interpret: bool = True,
 ) -> jax.Array:
     """Re-run Algorithm 1 for a subset of subarrays against current offsets.
 
@@ -245,7 +259,7 @@ def recalibrate_subarrays(
                 subarray_key(key, g), o, ladder, params, config)
         return jax.jit(jax.vmap(one))(idx, offs)
 
-    run = _block_calibrate(ladder, params, config, method, interpret)
+    run = _block_calibrate(ladder, params, config, method)
 
     def one(g, o):
         levels, _ = run(subarray_key(key, g), o[None])
@@ -275,11 +289,10 @@ def load_or_calibrate(
     *,
     mesh: Mesh | None = None,
     # "reference" is bit-identical to the fused Pallas kernel (enforced by
-    # tests/test_fleet.py) and much faster under the CPU interpreter; pass
-    # method="fused" with interpret=False on real TPU serving hosts.
+    # tests/test_fleet.py) and much faster under the CPU interpreter;
+    # "fused" lowers natively on a TPU.
     method: str = "reference",
     n_trials_ecr: int = 1024,
-    interpret: bool = True,
 ):
     """Return (levels [G, C], ecr [G], masks [G, C], cache_hit).
 
@@ -300,7 +313,7 @@ def load_or_calibrate(
 
     offsets = manufacture_fleet(key, cfg, params)
     cal = calibrate_fleet(key, offsets, cfg, params, config,
-                          mesh=mesh, method=method, interpret=interpret)
+                          mesh=mesh, method=method)
     ladder = cfg.ladder(params)
     charges = fleet_calib_charges(ladder, cal.levels, params)
     ecr, masks = measure_ecr_fleet(

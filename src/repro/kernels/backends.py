@@ -3,8 +3,8 @@
 The serving stack picks *where* a packed GeMV executes by name instead of
 threading ``interpret``/oracle flags through every call site:
 
-  * ``pallas``    — the Pallas TPU kernel; lowers natively on TPU and falls
-    back to interpret mode elsewhere (this container is CPU-only).
+  * ``pallas``    — the Pallas TPU kernel; lowers natively on a TPU and runs
+    in the interpreter elsewhere (``kernels/platform.interpret_mode``).
   * ``interpret`` — the same Pallas kernel forced through the interpreter,
     regardless of platform.  Useful for debugging kernel changes on TPU.
   * ``reference`` — the pure-jnp oracle (kernels/ref.py).
@@ -89,14 +89,9 @@ def backend_names() -> tuple[str, ...]:
     return tuple(sorted(_REGISTRY))
 
 
-def _pallas_interpret() -> bool:
-    # Lower natively only where the BlockSpecs actually target hardware.
-    return jax.default_backend() != "tpu"
-
-
-def _pallas_entries(interpret, check=False):
-    """The four kernel entries at a fixed interpret policy (callable so the
-    ``pallas`` backend re-reads the platform on every call).
+def _pallas_entries(interpret: bool | None, check=False):
+    """The four kernel entries at a fixed interpret policy (None lets the
+    kernels resolve it from the platform when they trace).
 
     ``check=True`` runs the static contract checker
     (repro.analysis.contracts) before every launch; the ``interpret``
@@ -115,7 +110,7 @@ def _pallas_entries(interpret, check=False):
              n_block=None, k_block=None):
         preflight("gemv", x, planes, layout=layout, logical_k=logical_k,
                   mode=mode, n_block=n_block, k_block=k_block)
-        return bitplane_gemv(x, planes, mode=mode, interpret=interpret(),
+        return bitplane_gemv(x, planes, mode=mode, interpret=interpret,
                              layout=layout, logical_k=logical_k,
                              n_block=n_block, k_block=k_block)
 
@@ -126,7 +121,7 @@ def _pallas_entries(interpret, check=False):
                   col_ids=col_ids, window_block=window_block, mode=mode,
                   n_block=n_block, k_block=k_block)
         return bitplane_gemv_placed(
-            x, planes, col_ids, mode=mode, interpret=interpret(),
+            x, planes, col_ids, mode=mode, interpret=interpret,
             layout=layout, logical_k=logical_k, window_block=window_block,
             n_block=n_block, k_block=k_block)
 
@@ -135,7 +130,7 @@ def _pallas_entries(interpret, check=False):
         preflight("gemm", x, planes, layout=layout, logical_k=logical_k,
                   mode=mode, b_block=b_block, n_block=n_block,
                   k_block=k_block)
-        return bitplane_gemm(x, planes, mode=mode, interpret=interpret(),
+        return bitplane_gemm(x, planes, mode=mode, interpret=interpret,
                              layout=layout, logical_k=logical_k,
                              b_block=b_block, n_block=n_block,
                              k_block=k_block)
@@ -147,7 +142,7 @@ def _pallas_entries(interpret, check=False):
                   col_ids=col_ids, window_block=window_block, mode=mode,
                   b_block=b_block, n_block=n_block, k_block=k_block)
         return bitplane_gemm_placed(
-            x, planes, col_ids, mode=mode, interpret=interpret(),
+            x, planes, col_ids, mode=mode, interpret=interpret,
             layout=layout, logical_k=logical_k, window_block=window_block,
             b_block=b_block, n_block=n_block, k_block=k_block)
 
@@ -175,19 +170,20 @@ def _ref_gemv(x, planes, mode="folded", *, layout="dense", logical_k=None,
 def _ref_gemv_placed(x, planes, col_ids, mode="folded", *, layout="dense",
                      logical_k=None, window_block=None, b_block=None,
                      n_block=None, k_block=None):
-    planes = _densify(planes, layout, logical_k)
-    if layout == "bitpack8" and planes.shape[1] != x.shape[1]:
-        x = jnp.pad(x, ((0, 0), (0, planes.shape[1] - x.shape[1])))
-    return ref.bitplane_gemv_placed_ref(x, planes, col_ids)
+    # Gather the logical columns before unpacking: a column's words unpack
+    # to that column's bits, so the order changes nothing but the scratch
+    # (a placed window can be twice the logical width).
+    planes = jnp.take(planes, col_ids, axis=2)
+    return _ref_gemv(x, planes, mode, layout=layout, logical_k=logical_k)
 
 
-_pl = _pallas_entries(_pallas_interpret)
+_pl = _pallas_entries(None)
 register_backend(Backend(
     name="pallas",
     gemv=_pl[0], gemv_placed=_pl[1], gemm=_pl[2], gemm_placed=_pl[3],
 ))
 
-_it = _pallas_entries(lambda: True, check=True)
+_it = _pallas_entries(True, check=True)
 register_backend(Backend(
     name="interpret",
     gemv=_it[0], gemv_placed=_it[1], gemm=_it[2], gemm_placed=_it[3],

@@ -40,11 +40,14 @@ from jax.experimental import pallas as pl
 
 from repro.analysis.errors import ContractViolation
 
+from .platform import interpret_mode
+
 # The kernel bodies are the GeMV ones with the K reduction axis moved to
 # grid position 2 (after the new batch axis); only the grid/BlockSpec
 # plumbing differs.
 from .bitplane_gemv import (_gemv_kernel, _gemv_placed_kernel, _k_tiling,
-                            _n_tiling, _placed_n_block, _sign_fix)
+                            _n_tiling, _placed_n_block, _sign_fix,
+                            check_folded_bits)
 
 B_BLOCK = 128
 K_BLOCK = 256
@@ -77,7 +80,7 @@ def bitplane_gemm(
     x: jax.Array,        # [B, K] int8 activations (any B, padded here)
     planes: jax.Array,   # [WB, K, N] int8 bits | [WB, K/8, N] uint8 words
     mode: str = "planes",
-    interpret: bool = True,
+    interpret: bool | None = None,
     layout: str = "dense",
     logical_k: int | None = None,
     b_block: int | None = None,
@@ -90,6 +93,7 @@ def bitplane_gemm(
     (kernels/autotune.py); non-multiple shapes pad with zeros."""
     b, k = x.shape
     wb, _, n = planes.shape
+    check_folded_bits("bitplane_gemm", mode, wb)
     xp, pp, pkb, xkb, k_steps = _k_tiling(x, planes, layout, logical_k,
                                           kernel="bitplane_gemm",
                                           k_block=k_block)
@@ -111,7 +115,7 @@ def bitplane_gemm(
         ],
         out_specs=pl.BlockSpec((bb, nb), lambda jb, jn, jk: (jb, jn)),
         out_shape=jax.ShapeDtypeStruct((bp, n_pad), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, pp)
     return unsigned[:b, :n] - _sign_fix(x, wb)
 
@@ -125,7 +129,7 @@ def bitplane_gemm_placed(
     planes: jax.Array,    # [WB, K(/8), W] physical window (placed layout)
     col_ids: jax.Array,   # [N] int32 logical -> window column map
     mode: str = "planes",
-    interpret: bool = True,
+    interpret: bool | None = None,
     layout: str = "dense",
     logical_k: int | None = None,
     window_block: int | None = None,
@@ -145,6 +149,7 @@ def bitplane_gemm_placed(
     b, k = x.shape
     wb, _, w_len = planes.shape
     (n,) = col_ids.shape
+    check_folded_bits("bitplane_gemm_placed", mode, wb)
     xp, pp, pkb, xkb, k_steps = _k_tiling(x, planes, layout, logical_k,
                                           kernel="bitplane_gemm_placed",
                                           k_block=k_block)
@@ -176,6 +181,6 @@ def bitplane_gemm_placed(
         ],
         out_specs=pl.BlockSpec((bb, nb), lambda jb, jn, jk: (jb, jn)),
         out_shape=jax.ShapeDtypeStruct((bp, n), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, col_ids.astype(jnp.int32)[None, :], pp)
     return unsigned[:b] - _sign_fix(x, wb)

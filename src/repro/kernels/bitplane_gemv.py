@@ -58,6 +58,8 @@ from jax.experimental import pallas as pl
 
 from repro.analysis.errors import ContractViolation
 
+from .platform import interpret_mode
+
 K_BLOCK = 256
 N_BLOCK = 256
 
@@ -116,24 +118,62 @@ def _unpack_bits(words: jax.Array) -> jax.Array:
     return bits.reshape(wb, kw * 8, nb).astype(jnp.int8)
 
 
+def _dot_i8(a: jax.Array, b: jax.Array) -> jax.Array:
+    """[M, K] int8 x [K, N] int8 -> [M, N] int32 on the MXU."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
 def _accumulate(x, planes, out_shape, mode: str, n_bits: int):
-    """Shared MXU accumulation: x [B, Kb] x planes [WB, Kb, Nb] -> [B, Nb]."""
+    """Shared MXU accumulation: x [B, Kb] int8 x planes [WB, Kb, Nb] int8
+    -> [B, Nb] int32.  Both MXU operands stay int8 (the chip's integer
+    matmul takes int8 x int8 -> int32, not int32 operands)."""
     if mode == "folded":
-        # Fold bit-planes to int8 weights in VMEM, single MXU pass.
+        # Fold bit-planes to weights in VMEM, single MXU pass.  A folded
+        # weight is <= 2^WB - 1, which fits int8 for WB <= 7 (the wrappers
+        # reject WB = 8 in this mode).
         w = jnp.zeros(planes.shape[1:], jnp.int32)
         for b in range(n_bits):
             w = w + (planes[b].astype(jnp.int32) << b)
-        return jax.lax.dot_general(
-            x, w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        return _dot_i8(x, w.astype(jnp.int8))
     # Faithful PUD schedule: one pass per plane, shift-accumulate.
     acc = jnp.zeros(out_shape, jnp.int32)
     for b in range(n_bits):
-        part = jax.lax.dot_general(
-            x, planes[b].astype(jnp.int32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-        acc = acc + (part << b)
+        acc = acc + (_dot_i8(x, planes[b]) << b)
     return acc
+
+
+def _select_columns(planes: jax.Array, cols: jax.Array) -> jax.Array:
+    """Gather window columns: [WB, Kr, P] x cols [1, Nb] -> [WB, Kr, Nb].
+
+    A one-hot selection matrix S[p, n] = (p == cols[n]) applied on the
+    MXU — the form the chip's compiler lowers (it has no general 3-D
+    gather).  Exact: every output element is one stored value times 1.
+    Dense 0/1 planes select in int8; bit-packed words (0..255) select in
+    bf16, which holds every byte value exactly.
+    """
+    wb, kr, p = planes.shape
+    nb = cols.shape[-1]
+    hit = jax.lax.broadcasted_iota(jnp.int32, (p, nb), 0) == cols
+    flat = planes.reshape(wb * kr, p)
+    if planes.dtype == jnp.uint8:
+        sel = jax.lax.dot_general(
+            flat.astype(jnp.int32).astype(jnp.bfloat16),
+            hit.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        sel = sel.astype(jnp.int32).astype(jnp.uint8)
+    else:
+        sel = _dot_i8(flat, hit.astype(jnp.int8)).astype(jnp.int8)
+    return sel.reshape(wb, kr, nb)
+
+
+def check_folded_bits(kernel: str, mode: str, n_bits: int) -> None:
+    """Folded mode keeps weights in int8: reject WB > 7."""
+    if mode == "folded" and n_bits > 7:
+        raise ContractViolation(
+            kernel, "folded-weight-bits",
+            f"folded mode needs weight_bits <= 7 (an int8 MXU operand), "
+            f"got {n_bits}; use mode='planes'")
 
 
 def _gemv_kernel(x_ref, planes_ref, out_ref, *, mode: str, n_bits: int,
@@ -148,7 +188,7 @@ def _gemv_kernel(x_ref, planes_ref, out_ref, *, mode: str, n_bits: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    x = x_ref[...].astype(jnp.int32)              # [B, Kb]
+    x = x_ref[...]                                # [B, Kb] int8
     planes = planes_ref[...]
     if packed:
         planes = _unpack_bits(planes)
@@ -174,9 +214,9 @@ def _gemv_placed_kernel(x_ref, cols_ref, planes_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    x = x_ref[...].astype(jnp.int32)              # [B, Kb]
-    cols = cols_ref[0, :] % window_block           # [Nb] in-block residues
-    planes = jnp.take(planes_ref[...], cols, axis=2)   # [WB, Kb(/8), Nb]
+    x = x_ref[...]                                # [B, Kb] int8
+    cols = cols_ref[...] % window_block           # [1, Nb] in-block residues
+    planes = _select_columns(planes_ref[...], cols)   # [WB, Kb(/8), Nb]
     if packed:
         planes = _unpack_bits(planes)
     out_ref[...] += _accumulate(x, planes, out_ref.shape, mode, n_bits)
@@ -207,6 +247,7 @@ def _k_tiling(x: jax.Array, planes: jax.Array, layout: str,
     executing anything).
     """
     k = x.shape[1]
+    x = x.astype(jnp.int8)               # the MXU operand dtype
     if layout == "bitpack8":
         kw = planes.shape[1]
         if (logical_k or kw * 8) != k or k > kw * 8:
@@ -288,7 +329,7 @@ def bitplane_gemv(
     x: jax.Array,        # [B, K] int8 activations
     planes: jax.Array,   # [WB, K, N] int8 bits | [WB, K/8, N] uint8 words
     mode: str = "planes",
-    interpret: bool = True,
+    interpret: bool | None = None,
     layout: str = "dense",
     logical_k: int | None = None,
     n_block: int | None = None,
@@ -305,6 +346,7 @@ def bitplane_gemv(
     """
     b, k = x.shape
     wb, _, n = planes.shape
+    check_folded_bits("bitplane_gemv", mode, wb)
     xp, pp, pkb, xkb, k_steps = _k_tiling(x, planes, layout, logical_k,
                                           k_block=k_block)
     nb, n_pad = _n_tiling(n, n_block, "bitplane_gemv")
@@ -322,7 +364,7 @@ def bitplane_gemv(
         ],
         out_specs=pl.BlockSpec((b, nb), lambda jn, jk: (0, jn)),
         out_shape=jax.ShapeDtypeStruct((b, n_pad), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, pp)
     return unsigned[:, :n] - _sign_fix(x, wb)
 
@@ -336,7 +378,7 @@ def bitplane_gemv_placed(
     planes: jax.Array,    # [WB, K(/8), W] physical window (placed layout)
     col_ids: jax.Array,   # [N] int32 logical -> window column map
     mode: str = "planes",
-    interpret: bool = True,
+    interpret: bool | None = None,
     layout: str = "dense",
     logical_k: int | None = None,
     window_block: int | None = None,
@@ -359,6 +401,7 @@ def bitplane_gemv_placed(
     b, k = x.shape
     wb, _, w_len = planes.shape
     (n,) = col_ids.shape
+    check_folded_bits("bitplane_gemv_placed", mode, wb)
     xp, pp, pkb, xkb, k_steps = _k_tiling(x, planes, layout, logical_k,
                                           kernel="bitplane_gemv_placed",
                                           k_block=k_block)
@@ -388,6 +431,6 @@ def bitplane_gemv_placed(
         ],
         out_specs=pl.BlockSpec((b, nb), lambda jn, jk: (0, jn)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, col_ids.astype(jnp.int32)[None, :], pp)
     return unsigned - _sign_fix(x, wb)

@@ -13,7 +13,7 @@ reduction — memory-bound by design, so the BlockSpec keeps each block's
 working set (8+2 planes * 4 B * COL_BLOCK) comfortably inside VMEM.
 
 Noise is passed in as standard-normal draws (host PRNG) so the kernel is
-deterministic and bit-exact against ref.py in interpret mode.
+deterministic and bit-exact against ref.py.
 """
 from __future__ import annotations
 
@@ -25,6 +25,8 @@ from jax.experimental import pallas as pl
 
 from repro.analysis.errors import ContractViolation
 from repro.pud.physics import NEUTRAL, PhysicsParams
+
+from .platform import interpret_mode
 
 TRIAL_BLOCK = 8
 COL_BLOCK = 1024
@@ -56,12 +58,21 @@ def _majx_kernel(charge_ref, offset_ref, noise_ref, out_ref, *,
 # The unfused path runs three jitted stages per iteration — levels_to_charges
 # (gather), maj_outputs (sense), bias/level-step (reduce + select) — each
 # round-tripping [S, C]-shaped intermediates through HBM.  This kernel fuses
-# them: per column block it gathers the ladder's per-level charge/swing sums
-# (static unrolled select over <= 8 levels, no dynamic gather needed on TPU),
-# senses all sample blocks while accumulating the per-column bias in the
-# output block (revisited across the innermost sample-grid axis), and applies
-# the threshold level step on the last sample block.  One HBM read of the
-# inputs, one write of [C] levels + [C] bias.
+# them: per column block it looks up the sensing's bitline voltage and noise
+# sigma (static tables, below), senses all sample blocks while accumulating
+# the per-column bias in the output block (revisited across the innermost
+# sample-grid axis), and applies the threshold level step on the last sample
+# block.  One HBM read of the inputs, one write of [C] levels + [C] bias.
+#
+# Operand bits are 0/1, so one sensing's charge sum is (ones among the M
+# operands + the level's calibration-row sum + constant rows) and its
+# swing^2 sum is (M + the level's calibration-row swing + constant rows):
+# the voltage depends only on (level, ones) and the sigma only on the level.
+# Both come in as static tables (``core/fleet.sense_tables``) and the
+# decision is ``sigma * noise > (0.5 + offset) - v`` — a product and two
+# differences, no division, square root or multiply-add a compiler could
+# contract — so this kernel and ``ref.calib_iter_ref`` decide identically on
+# every backend, not just on the CPU.
 
 CAL_SAMPLE_BLOCKS = (64, 32, 16, 8, 4, 2, 1)
 CAL_COL_BLOCKS = (1024, 512, 256, 128)
@@ -79,41 +90,38 @@ def _pick_block(n: int, candidates: tuple[int, ...],
 
 def _calib_iter_kernel(inputs_ref, noise_ref, levels_ref, offset_ref,
                        levels_out_ref, bias_ref, *,
-                       params: PhysicsParams, n_fracs: int,
-                       level_qsum: tuple[float, ...],
-                       level_swing: tuple[float, ...],
+                       level_v: tuple[tuple[float, ...], ...],
+                       level_sigma: tuple[float, ...],
                        n_samples: int, n_sample_blocks: int,
-                       threshold: float, maj_inputs: int,
-                       const_charge_sum: float, const_swing_sq: float):
-    j = pl.program_id(1)                          # sample-block (innermost)
+                       threshold: float, maj_inputs: int):
+    j = pl.program_id(2)                          # sample-block (innermost)
 
     @pl.when(j == 0)
     def _init():
         bias_ref[...] = jnp.zeros_like(bias_ref)
 
-    levels = levels_ref[...]                      # [Cb] int32
+    levels = levels_ref[...]                      # [1, Cb] int32
     inp = inputs_ref[...]                         # [Sb, M, Cb] bits as f32
     noise = noise_ref[...]                        # [Sb, Cb]
-    offset = offset_ref[...]                      # [Cb]
+    offset = offset_ref[...]                      # [1, Cb]
 
-    # Ladder lookup: per-level calibration-row charge sum and swing^2 sum are
-    # static scalars; select instead of gathering [n_rows, C] charges.
-    calib_qsum = jnp.zeros(levels.shape, jnp.float32)
-    calib_swing = jnp.zeros(levels.shape, jnp.float32)
-    for lvl, (q, s) in enumerate(zip(level_qsum, level_swing)):
+    # Table lookups as static selects (no dynamic gather on the chip):
+    # per column, the voltage for every ones-count and the sigma.
+    sigma = jnp.zeros(levels.shape, jnp.float32)
+    v_by_ones = [jnp.zeros(levels.shape, jnp.float32)
+                 for _ in range(maj_inputs + 1)]
+    for lvl, (vs, sg) in enumerate(zip(level_v, level_sigma)):
         sel = levels == lvl
-        calib_qsum = jnp.where(sel, jnp.float32(q), calib_qsum)
-        calib_swing = jnp.where(sel, jnp.float32(s), calib_swing)
-
-    charge_sum = inp.sum(axis=1) + calib_qsum[None, :] + const_charge_sum
-    v = params.bitline_voltage(charge_sum, params.n_simra_rows)
-    swing_sq = (((2.0 * (inp - NEUTRAL)) ** 2).sum(axis=1)
-                + calib_swing[None, :] + const_swing_sq)
-    sigma = params.sensing_sigma(jnp.float32(n_fracs), swing_sq)
-    out = ((v + sigma * noise) > (NEUTRAL + offset[None, :])).astype(
-        jnp.float32)
-    truth = (inp.sum(axis=1) > maj_inputs // 2).astype(jnp.float32)
-    bias_ref[...] += (out - truth).sum(axis=0) / n_samples
+        sigma = jnp.where(sel, jnp.float32(sg), sigma)
+        v_by_ones = [jnp.where(sel, jnp.float32(v), acc)
+                     for v, acc in zip(vs, v_by_ones)]
+    ones = inp.sum(axis=1)                        # [Sb, Cb], exact 0..M
+    v = jnp.zeros(ones.shape, jnp.float32)
+    for k, vk in enumerate(v_by_ones):
+        v = jnp.where(ones == k, vk, v)
+    out = (sigma * noise > (NEUTRAL + offset) - v).astype(jnp.float32)
+    truth = (ones > maj_inputs // 2).astype(jnp.float32)
+    bias_ref[...] += (out - truth).sum(axis=0, keepdims=True) / n_samples
 
     @pl.when(j == n_sample_blocks - 1)
     def _step():
@@ -121,58 +129,66 @@ def _calib_iter_kernel(inputs_ref, noise_ref, levels_ref, offset_ref,
         step = jnp.where(bias > threshold, -1, 0) + jnp.where(
             bias < -threshold, 1, 0)
         levels_out_ref[...] = jnp.clip(
-            levels + step, 0, len(level_qsum) - 1)
+            levels + step, 0, len(level_sigma) - 1)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("params", "n_fracs", "level_qsum",
-                              "level_swing", "threshold", "maj_inputs",
-                              "const_charge_sum", "const_swing_sq",
-                              "interpret"))
+    jax.jit, static_argnames=("level_v", "level_sigma", "threshold",
+                              "maj_inputs", "interpret"))
 def calib_iter_fused(
-    inputs: jax.Array,        # [S, M, C] float32 operand bits
-    noise: jax.Array,         # [S, C] float32 standard normal
-    levels: jax.Array,        # [C] int32 current ladder levels
-    sense_offset: jax.Array,  # [C] float32
-    params: PhysicsParams,
-    n_fracs: int,
-    level_qsum: tuple[float, ...],    # per-level calib-row charge sum
-    level_swing: tuple[float, ...],   # per-level calib-row swing^2 sum
+    inputs: jax.Array,        # [G?, S, M, C] float32 operand bits
+    noise: jax.Array,         # [G?, S, C] float32 standard normal
+    levels: jax.Array,        # [G?, C] int32 current ladder levels
+    sense_offset: jax.Array,  # [G?, C] float32
+    level_v: tuple[tuple[float, ...], ...],   # [L][M+1] bitline voltage
+    level_sigma: tuple[float, ...],           # [L] sensing noise sigma
     threshold: float,
     maj_inputs: int = 5,
-    const_charge_sum: float = 0.0,
-    const_swing_sq: float = 0.0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """One fused Algorithm-1 iteration; returns (new levels [C], bias [C])."""
-    s, m, c = inputs.shape
+    """One fused Algorithm-1 iteration; returns (new levels, bias), each
+    [G?, C].  ``level_v``/``level_sigma`` come from
+    ``core/fleet.sense_tables``.
+
+    An optional leading subarray axis G is part of the kernel grid (a
+    ``vmap`` of the kernel would give its per-column blocks a squeezed
+    sublane dimension, which the chip's compiler does not tile).  The
+    per-column vectors travel as [G, 1, C] so every block's last two
+    dimensions are (1, Cb).
+    """
+    batched = inputs.ndim == 4
+    if not batched:
+        inputs, noise = inputs[None], noise[None]
+        levels, sense_offset = levels[None], sense_offset[None]
+    g, s, m, c = inputs.shape
     sb = _pick_block(s, CAL_SAMPLE_BLOCKS)
     cb = _pick_block(c, CAL_COL_BLOCKS)
-    grid = (c // cb, s // sb)                     # sample axis innermost
+    grid = (g, c // cb, s // sb)                  # sample axis innermost
     kernel = functools.partial(
-        _calib_iter_kernel, params=params, n_fracs=n_fracs,
-        level_qsum=level_qsum, level_swing=level_swing, n_samples=s,
-        n_sample_blocks=s // sb, threshold=threshold, maj_inputs=maj_inputs,
-        const_charge_sum=const_charge_sum, const_swing_sq=const_swing_sq)
-    return pl.pallas_call(
+        _calib_iter_kernel, level_v=level_v, level_sigma=level_sigma,
+        n_samples=s, n_sample_blocks=s // sb, threshold=threshold,
+        maj_inputs=maj_inputs)
+    col = pl.BlockSpec((None, 1, cb), lambda gi, i, j: (gi, 0, i))
+    new_levels, bias = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((sb, m, cb), lambda i, j: (j, 0, i)),
-            pl.BlockSpec((sb, cb), lambda i, j: (j, i)),
-            pl.BlockSpec((cb,), lambda i, j: (i,)),
-            pl.BlockSpec((cb,), lambda i, j: (i,)),
+            pl.BlockSpec((None, sb, m, cb), lambda gi, i, j: (gi, j, 0, i)),
+            pl.BlockSpec((None, sb, cb), lambda gi, i, j: (gi, j, i)),
+            col,
+            col,
         ],
-        out_specs=[
-            pl.BlockSpec((cb,), lambda i, j: (i,)),
-            pl.BlockSpec((cb,), lambda i, j: (i,)),
-        ],
+        out_specs=[col, col],
         out_shape=[
-            jax.ShapeDtypeStruct((c,), jnp.int32),
-            jax.ShapeDtypeStruct((c,), jnp.float32),
+            jax.ShapeDtypeStruct((g, 1, c), jnp.int32),
+            jax.ShapeDtypeStruct((g, 1, c), jnp.float32),
         ],
-        interpret=interpret,
-    )(inputs, noise, levels, sense_offset)
+        interpret=interpret_mode(interpret),
+    )(inputs, noise, levels[:, None, :], sense_offset[:, None, :])
+    new_levels, bias = new_levels[:, 0], bias[:, 0]
+    if not batched:
+        return new_levels[0], bias[0]
+    return new_levels, bias
 
 
 @functools.partial(jax.jit, static_argnames=("params", "n_fracs", "interpret"))
@@ -182,7 +198,7 @@ def majx_sense(
     noise: jax.Array,         # [T, C] float32 standard normal
     params: PhysicsParams = PhysicsParams(),
     n_fracs: int = 0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Sensed bits [T, C] for T SiMRA events over C columns."""
     t, r, c = charge.shape
@@ -204,5 +220,5 @@ def majx_sense(
         ],
         out_specs=pl.BlockSpec((TRIAL_BLOCK, COL_BLOCK), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, c), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(charge, sense_offset, noise)

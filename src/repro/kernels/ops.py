@@ -1,8 +1,8 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on real
-TPU hardware pass interpret=False (the BlockSpecs are TPU-shaped: 128-lane
-aligned columns, MXU-aligned matmul tiles).
+Every entry lowers natively on a TPU and runs in the Pallas interpreter
+elsewhere (``kernels/platform.interpret_mode``); a backend name
+(kernels/backends.py) picks the lowering per call.
 """
 from __future__ import annotations
 
@@ -11,7 +11,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .autotune import TunedTile, plan_for_entry, tune_kernel, tuning_key
-from .backends import get_backend
+from .backends import DEFAULT_BACKEND, get_backend
 from .bitplane_gemm import B_BLOCK, bitplane_gemm, bitplane_gemm_placed
 from .bitplane_gemv import (DEGENERATE_TILE_FLOOR, K_BLOCK, N_BLOCK,
                             bitplane_gemv, bitplane_gemv_placed)
@@ -23,6 +23,7 @@ __all__ = [
     "majx_sense", "calib_iter_fused", "bitplane_gemv",
     "bitplane_gemv_placed", "bitplane_gemm", "bitplane_gemm_placed",
     "pud_matmul", "pud_matmul_sharded", "pud_gemv", "quantize_activations",
+    "round_to_dtype",
     # Autotuner surface (kernels/autotune.py): plans ride packs and the
     # tuning cache through these names.
     "TunedTile", "plan_for_entry", "tune_kernel", "tuning_key",
@@ -35,13 +36,34 @@ __all__ = [
 ]
 
 
+def round_to_dtype(x: jax.Array, dtype) -> jax.Array:
+    """float32 ``x`` rounded to the precision of the float ``dtype``.
+
+    XLA may compute a bf16 value at f32 precision when it fuses the op
+    that produces it into its consumer, and on a TPU whether it fuses
+    depends on the shapes.  An explicit ``reduce_precision`` is a rounding
+    it keeps, so what follows sees the same value at every batch size.
+    Integer and 32-bit or wider dtypes leave ``x`` as it is.
+    """
+    if not jnp.issubdtype(dtype, jnp.floating):
+        return x
+    fi = jnp.finfo(dtype)
+    if fi.bits >= 32:
+        return x
+    return jax.lax.reduce_precision(x, exponent_bits=fi.nexp,
+                                    mantissa_bits=fi.nmant)
+
+
 def quantize_activations(x: jax.Array, clip: float = 4.0) -> tuple[jax.Array, jax.Array]:
     """Per-row symmetric int8 quantization for the PUD GeMV input.
 
     Row-independent by construction (per-row scale), so batched and
     per-request execution quantize each request identically — the property
-    the batched-vs-sequential bit-exactness guarantee rests on.
+    the batched-vs-sequential bit-exactness guarantee rests on.  It runs in
+    float32 on ``x`` rounded to its own dtype (``round_to_dtype``), so the
+    batch size cannot change how much precision the input carries.
     """
+    x = round_to_dtype(x.astype(jnp.float32), x.dtype)
     scale = jnp.maximum(jnp.abs(x).max(axis=-1, keepdims=True), 1e-6) / 127.0
     q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
     return q, scale
@@ -52,7 +74,6 @@ def pud_matmul(
     planes: jax.Array,     # [WB, K(/8), N] bit-planes / bit-words
     w_scale: jax.Array,    # [N] or scalar dequant scale
     mode: str = "folded",
-    interpret: bool = True,
     col_ids: jax.Array | None = None,   # [N] window map -> placed kernel
     backend: str | None = None,         # named backend (kernels/backends.py)
     layout: str = "dense",              # plane storage (repro/pud/packed.py)
@@ -72,9 +93,8 @@ def pud_matmul(
     (repro/pud/placement.py) and the column gather runs fused in the kernel.
     ``layout``/``logical_k``/``window_block`` carry the pack-format
     metadata of a ``PackedTensor`` (bit-packed words unpack inside the
-    kernel).  ``backend`` names a registered lowering; without one the
-    legacy ``interpret`` flag picks between the interpreted and native
-    Pallas kernel.  All backends are bit-exact against each other.
+    kernel).  ``backend`` names a registered lowering (default
+    ``DEFAULT_BACKEND``).  All backends are bit-exact against each other.
 
     ``check_contracts=True`` runs the static kernel-contract checker
     (repro/analysis/contracts.py) over the resolved entry point before
@@ -91,7 +111,7 @@ def pud_matmul(
     falls back to the divisor heuristic unchanged.
     """
     xq, x_scale = quantize_activations(x)
-    be = get_backend(backend or ("interpret" if interpret else "pallas"))
+    be = get_backend(backend or DEFAULT_BACKEND)
     batched = xq.shape[0] > 1
     entry = "gemm" if batched else "gemv"
     plan = plan_for_entry(tile_plan, entry)
@@ -143,7 +163,6 @@ def pud_matmul_sharded(
     x: jax.Array,          # [B, K] float activations (replicated per device)
     st,                    # ShardedPackedTensor: children stacked [S, ...]
     mode: str = "folded",
-    interpret: bool = True,
     backend: str | None = None,
     check_contracts: bool = False,
 ) -> jax.Array:
@@ -154,8 +173,9 @@ def pud_matmul_sharded(
     common per-device shape and stacked on a leading shard axis S that maps
     onto ``st.axis`` of ``st.mesh``.  Each device runs the ordinary
     ``pud_matmul`` on its own shard — its own planes, dequant scales and
-    (placed layout) ``col_ids`` — with ``x`` replicated in, then the
-    per-shard outputs reassemble by static column slices.
+    (placed layout) ``col_ids`` — with ``x`` replicated in; every device
+    then gathers the per-shard outputs, which reassemble, replicated, by
+    static column slices.
 
     Bit-exact against the unsharded path by construction: activation
     quantization is per-row (identical on every replica), the integer
@@ -164,7 +184,6 @@ def pud_matmul_sharded(
     expression ``pud_matmul`` computes — float columns never cross a shard
     boundary, so no re-association happens anywhere.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if st.mesh is None:
@@ -173,27 +192,32 @@ def pud_matmul_sharded(
             "PUDFleetSession.pack / pack_model_sharded(mesh=...)")
     axis = st.axis
     placed = st.col_ids is not None
-    kw = dict(mode=mode, interpret=interpret,
-              backend=backend or st.backend, layout=st.layout,
+    kw = dict(mode=mode, backend=backend or st.backend, layout=st.layout,
               logical_k=st.logical_k, window_block=st.window_block,
               check_contracts=check_contracts, tile_plan=st.tile_plan)
 
+    # Every device gathers all shards' columns inside the shard_map, so the
+    # result leaves it replicated.  Left sharded, the compiler may keep the
+    # model's next ops (residual, norms) split over the hidden axis and sum
+    # partial reductions across devices, re-associating float sums the
+    # one-device path adds in another order.
     if placed:
         def body(xr, planes, scale, col_ids):
-            return pud_matmul(xr, planes[0], scale[0],
-                              col_ids=col_ids[0], **kw)[None]
+            y = pud_matmul(xr, planes[0], scale[0], col_ids=col_ids[0], **kw)
+            return jax.lax.all_gather(y, axis)
 
-        f = shard_map(body, mesh=st.mesh,
-                      in_specs=(P(), P(axis), P(axis), P(axis)),
-                      out_specs=P(axis), check_rep=False)
+        f = jax.shard_map(body, mesh=st.mesh,
+                          in_specs=(P(), P(axis), P(axis), P(axis)),
+                          out_specs=P(), check_vma=False)
         y = f(x, st.planes, st.scale, st.col_ids)
     else:
         def body(xr, planes, scale):
-            return pud_matmul(xr, planes[0], scale[0], **kw)[None]
+            return jax.lax.all_gather(
+                pud_matmul(xr, planes[0], scale[0], **kw), axis)
 
-        f = shard_map(body, mesh=st.mesh,
-                      in_specs=(P(), P(axis), P(axis)),
-                      out_specs=P(axis), check_rep=False)
+        f = jax.shard_map(body, mesh=st.mesh,
+                          in_specs=(P(), P(axis), P(axis)),
+                          out_specs=P(), check_vma=False)
         y = f(x, st.planes, st.scale)
     # [S, B, Np] -> [B, N]: drop per-shard padding columns, concatenate in
     # logical order (shards own contiguous column ranges by construction).
@@ -206,7 +230,6 @@ def pud_gemv(
     planes: jax.Array,
     w_scale: jax.Array,
     mode: str = "folded",
-    interpret: bool = True,
     col_ids: jax.Array | None = None,
     backend: str | None = None,
     layout: str = "dense",
@@ -220,7 +243,7 @@ def pud_gemv(
     Kept as the legacy single-request entry: a 1-D ``x`` [K] returns [N],
     a 2-D ``x`` [B, K] behaves exactly like ``pud_matmul``.
     """
-    kw = dict(mode=mode, interpret=interpret, col_ids=col_ids,
+    kw = dict(mode=mode, col_ids=col_ids,
               backend=backend, layout=layout, logical_k=logical_k,
               window_block=window_block, check_contracts=check_contracts,
               tile_plan=tile_plan)

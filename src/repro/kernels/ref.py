@@ -31,30 +31,23 @@ def calib_iter_ref(
     noise: jax.Array,         # [S, C] standard normal
     levels: jax.Array,        # [C] int32
     sense_offset: jax.Array,  # [C]
-    params: PhysicsParams,
-    n_fracs: int,
-    level_qsum: tuple[float, ...],
-    level_swing: tuple[float, ...],
+    level_v: tuple[tuple[float, ...], ...],   # [L][M+1] bitline voltage
+    level_sigma: tuple[float, ...],           # [L] sensing noise sigma
     threshold: float,
     maj_inputs: int = 5,
-    const_charge_sum: float = 0.0,
-    const_swing_sq: float = 0.0,
 ) -> tuple[jax.Array, jax.Array]:
     """Oracle for kernels/majx.calib_iter_fused (same math, pure jnp)."""
-    qsum = jnp.asarray(level_qsum, jnp.float32)[levels]      # [C]
-    swing = jnp.asarray(level_swing, jnp.float32)[levels]    # [C]
-    charge_sum = inputs.sum(axis=1) + qsum[None, :] + const_charge_sum
-    v = params.bitline_voltage(charge_sum, params.n_simra_rows)
-    swing_sq = (((2.0 * (inputs - NEUTRAL)) ** 2).sum(axis=1)
-                + swing[None, :] + const_swing_sq)
-    sigma = params.sensing_sigma(jnp.float32(n_fracs), swing_sq)
-    out = ((v + sigma * noise) > (NEUTRAL + sense_offset[None, :])).astype(
-        jnp.float32)
-    truth = (inputs.sum(axis=1) > maj_inputs // 2).astype(jnp.float32)
+    ones = inputs.sum(axis=1)                                  # [S, C]
+    v_tab = jnp.asarray(level_v, jnp.float32)                  # [L, M+1]
+    v = v_tab[levels[None, :], ones.astype(jnp.int32)]         # [S, C]
+    sigma = jnp.asarray(level_sigma, jnp.float32)[levels]      # [C]
+    out = (sigma[None, :] * noise
+           > (NEUTRAL + sense_offset[None, :]) - v).astype(jnp.float32)
+    truth = (ones > maj_inputs // 2).astype(jnp.float32)
     bias = (out - truth).sum(axis=0) / inputs.shape[0]
     step = jnp.where(bias > threshold, -1, 0) + jnp.where(
         bias < -threshold, 1, 0)
-    new_levels = jnp.clip(levels + step, 0, len(level_qsum) - 1)
+    new_levels = jnp.clip(levels + step, 0, len(level_sigma) - 1)
     return new_levels, bias
 
 

@@ -7,22 +7,31 @@ Target: TPU v5e pods — 256 chips/pod as (data=16, model=16); multi-pod adds a
 leading "pod" axis (2 pods = 512 chips).  "model" is the TP/EP axis (fast ICI
 within a pod slice); "data" carries DP + FSDP; "pod" carries cross-pod DP
 (gradient all-reduce over DCN/optical links).
+
+Every mesh here has ``Auto`` axis types: the compiler propagates shardings
+and ``with_sharding_constraint`` is a hint, which is what the model code's
+sharding hints and the fleet's ``shard_map`` regions are written for.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2):
-    """Small mesh for CPU multi-device tests (subprocess with forced host
-    device count)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small ("data", "model") mesh: CPU multi-device tests (forced host
+    device count) and the serving launcher's ``--mesh``."""
+    return _mesh((data, model), ("data", "model"))
 
 
 def make_mesh_for_devices():
@@ -35,7 +44,7 @@ def make_mesh_for_devices():
         if n % m == 0 and m <= n:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))
 
 
 def parse_mesh_spec(spec: str):
@@ -56,22 +65,11 @@ def parse_mesh_spec(spec: str):
     if data * model > n:
         raise ValueError(
             f"mesh {data}x{model} needs {data * model} devices but only "
-            f"{n} visible — set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={data * model}")
+            f"{n} {jax.devices()[0].platform} device(s) are visible")
     return make_host_mesh(data, model)
 
 
 def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh, portable
-    across JAX versions.
-
-    ``jax.set_mesh`` (0.6+) / ``jax.sharding.use_mesh`` (0.5.x) replaced the
-    older ``with mesh:`` resource-env context; on the jaxlib pinned here only
-    the latter exists.  All launchers and mesh tests go through this helper so
-    the call site never references a removed API.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on older JAX
+    """Context manager installing ``mesh`` as the ambient mesh
+    (``jax.set_mesh``); launchers and mesh tests enter meshes here."""
+    return jax.set_mesh(mesh)

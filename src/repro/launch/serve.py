@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.params import init_params, param_count
 from repro.pud.gemv import ATTN_PACKABLE, FFN_PACKABLE, PUDGemvConfig
 from repro.runtime.steps import make_serve_step
@@ -395,6 +396,8 @@ def main(argv=None) -> int:
     if args.mesh and args.monitor:
         ap.error("--mesh and --monitor are mutually exclusive (use "
                  "runtime.drift.FleetDriftMonitor programmatically)")
+    enable_compile_cache()
+    platform = jax.devices()[0].platform
 
     spec = get(args.arch)
     model = spec.make_smoke() if args.preset == "smoke" else spec.make_model()
@@ -426,7 +429,7 @@ def main(argv=None) -> int:
         model, params, tokens, args.gen, max_len, extras, prefix_len)
     dt = time.time() - t0
     print(f"  bf16 path: {args.batch * args.gen} tokens in {dt:.2f}s "
-          "(CPU wall; TPU perf comes from the dry-run roofline)")
+          f"({platform} wall, compilation included)")
 
     if args.mesh:
         if extras:
@@ -566,7 +569,7 @@ def main(argv=None) -> int:
               f"{sched['generated_tokens']} tokens in {sched['steps']} steps "
               f"({sched['batch_size']} slots, "
               f"occupancy {sched['slot_occupancy']:.1%}, "
-              f"{sched['wall_tok_s']:.1f} tok/s CPU wall)")
+              f"{sched['wall_tok_s']:.1f} tok/s {platform} wall)")
         if args.chunk_prefill:
             print(f"    chunked prefill: {sched['prefill_chunks']} chunks "
                   f"of {engine.chunk_prefill} tokens "

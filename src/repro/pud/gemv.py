@@ -20,12 +20,13 @@ Two coupled halves:
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.ops import pud_gemv, pud_matmul_sharded
+from repro.kernels.ops import pud_gemv, pud_matmul_sharded, round_to_dtype
 from repro.kernels.ref import pack_bitplanes, pack_plane_words
 
 from .bitserial import add8_counts, mul8_counts
@@ -50,11 +51,10 @@ ECR_PUDTUNE_T210 = 0.033
 class PUDGemvConfig:
     weight_bits: int = 4
     mode: str = "folded"         # "planes" (faithful) | "folded" (optimized)
-    interpret: bool = True       # CPU container; False on real TPU
     # Which projections pack_for_serving swaps onto the PUD path.
     packable: tuple[str, ...] = FFN_PACKABLE
-    # Named execution backend (kernels/backends.py); None falls back to the
-    # legacy interpret flag ("interpret" when True, "pallas" when False).
+    # Named execution backend (kernels/backends.py); None defers to the
+    # pack's stamp, then to the default backend.
     backend: str | None = None
 
 
@@ -73,46 +73,61 @@ def pack_linear(w: jax.Array, n_bits: int = 4,
     q in [-2^{b-1}, 2^{b-1}).  ``backend`` stamps the pack with the
     execution backend model forwards should dispatch it through.
     """
+    planes, scale = quantize_planes(w, n_bits, bitpack)
+    if not bitpack:
+        return PackedTensor(planes=planes, scale=scale, backend=backend)
+    return PackedTensor(planes=planes, scale=scale, backend=backend,
+                        layout=LAYOUT_BITPACK, logical_k=w.shape[0])
+
+
+@functools.partial(jax.jit, static_argnames=("n_bits", "bitpack"))
+def quantize_planes(w: jax.Array, n_bits: int,
+                    bitpack: bool) -> tuple[jax.Array, jax.Array]:
+    """[K, N] weights -> (planes, scale [N] f32) of ``pack_linear``.
+
+    One compiled program, so the int32 bit-plane intermediates fuse into
+    the int8/uint8 result instead of each materializing at full size (a
+    vocabulary-wide unembed would otherwise need several GiB of scratch).
+    """
     qmax = (1 << (n_bits - 1)) - 1
     scale = jnp.maximum(jnp.abs(w).max(axis=0), 1e-8) / qmax       # [N]
     q = jnp.clip(jnp.round(w / scale[None, :]), -qmax - 1, qmax)
     planes = pack_bitplanes(q.astype(jnp.int32), n_bits)
-    if not bitpack:
-        return PackedTensor(planes=planes, scale=scale.astype(jnp.float32),
-                            backend=backend)
-    return PackedTensor(planes=pack_plane_words(planes),
-                        scale=scale.astype(jnp.float32), backend=backend,
-                        layout=LAYOUT_BITPACK, logical_k=w.shape[0])
+    if bitpack:
+        planes = pack_plane_words(planes)
+    return planes, scale.astype(jnp.float32)
 
 
 def pud_linear(x: jax.Array, packed: "PackedTensor | dict",
                cfg: PUDGemvConfig = PUDGemvConfig(),
                backend: str | None = None) -> jax.Array:
-    """x: [..., K] float -> [..., N] float32 through the bit-plane GeMV.
+    """x: [..., K] float -> [..., N] float32 through the bit-plane GeMV,
+    rounded to x's precision (``kernels.ops.round_to_dtype``), so casting
+    it to x's dtype is exact and a consumer fused with it sees the same
+    values at every batch size.
 
     ``packed`` is a ``PackedTensor`` (or a legacy pack dict, coerced).
     The pack's layout metadata (dense vs bit-packed words, placed window
     stride) rides into the kernel dispatch.  Backend resolution: explicit
     ``backend`` arg > ``cfg.backend`` > the backend stamped on the pack
     (how a session's choice reaches model forwards, which call this with
-    the default config) > the legacy ``interpret`` flag.
+    the default config) > ``DEFAULT_BACKEND``.
     """
     if isinstance(packed, ShardedPackedTensor):
         lead = x.shape[:-1]
         x2 = x.reshape((-1, x.shape[-1]))
         y = pud_matmul_sharded(x2, packed, mode=cfg.mode,
-                               interpret=cfg.interpret,
                                backend=backend or cfg.backend)
-        return y.reshape(lead + (y.shape[-1],))
-    pt = as_packed_tensor(packed)
-    lead = x.shape[:-1]
-    x2 = x.reshape((-1, x.shape[-1]))
-    y = pud_gemv(x2, pt.planes, pt.scale,
-                 mode=cfg.mode, interpret=cfg.interpret,
-                 col_ids=pt.col_ids,
-                 backend=backend or cfg.backend or pt.backend,
-                 layout=pt.layout, logical_k=pt.logical_k,
-                 window_block=pt.window_block, tile_plan=pt.tile_plan)
+    else:
+        pt = as_packed_tensor(packed)
+        lead = x.shape[:-1]
+        x2 = x.reshape((-1, x.shape[-1]))
+        y = pud_gemv(x2, pt.planes, pt.scale,
+                     mode=cfg.mode, col_ids=pt.col_ids,
+                     backend=backend or cfg.backend or pt.backend,
+                     layout=pt.layout, logical_k=pt.logical_k,
+                     window_block=pt.window_block, tile_plan=pt.tile_plan)
+    y = round_to_dtype(y, x.dtype)
     return y.reshape(lead + (y.shape[-1],))
 
 

@@ -33,12 +33,16 @@ window hold zeros and are never addressed.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .gemv import ATTN_PACKABLE, FFN_PACKABLE, PUDGemvConfig, pack_linear
+from repro.kernels.ref import pack_plane_words
+
+from .gemv import (ATTN_PACKABLE, FFN_PACKABLE, PUDGemvConfig, pack_linear,
+                   quantize_planes)
 from .packed import (LAYOUT_BITPACK, PackedModel, PackedTensor,
                      ShardedPackedTensor)
 from .packed import packed_bytes  # noqa: F401  (legacy import location)
@@ -113,18 +117,12 @@ def _pack_placed(w: jax.Array, n_bits: int, tp: TensorPlacement,
     [L?, N] (absolute window positions) and ``window_block`` aux, where
     W = tp.region_size.
     """
-    from repro.kernels.ref import pack_plane_words
-
     local = np.asarray(tp.local_cols)
 
     def one(w2, loc):
-        pk = pack_linear(w2, n_bits, bitpack=False)
-        planes = jnp.zeros(pk.planes.shape[:2] + (tp.region_size,),
-                           jnp.int8)
         idx = jnp.asarray(loc, jnp.int32)
-        planes = planes.at[:, :, idx].set(pk.planes)
-        return PackedTensor(planes=pack_plane_words(planes), scale=pk.scale,
-                            col_ids=idx)
+        planes, scale = _placed_words(w2, idx, n_bits, tp.region_size)
+        return PackedTensor(planes=planes, scale=scale, col_ids=idx)
 
     kw = dict(backend=backend, layout=LAYOUT_BITPACK,
               logical_k=w.shape[-2], window_block=tp.window_block)
@@ -136,6 +134,16 @@ def _pack_placed(w: jax.Array, n_bits: int, tp: TensorPlacement,
         scale=jnp.stack([p.scale for p in packs]),
         col_ids=jnp.stack([p.col_ids for p in packs]),
         **kw)
+
+
+@functools.partial(jax.jit, static_argnames=("n_bits", "region"))
+def _placed_words(w: jax.Array, idx: jax.Array, n_bits: int,
+                  region: int) -> tuple[jax.Array, jax.Array]:
+    """Quantize, scatter the dense planes to window positions ``idx`` and
+    bit-pack the window, in one compiled program (bounded scratch)."""
+    planes, scale = quantize_planes(w, n_bits, bitpack=False)
+    window = jnp.zeros(planes.shape[:2] + (region,), jnp.int8)
+    return pack_plane_words(window.at[:, :, idx].set(planes)), scale
 
 
 def _pad_axis(a: jax.Array, axis: int, target: int, value=0) -> jax.Array:

@@ -22,7 +22,8 @@ divisor of N <= ``PLACE_BLOCK``, mirroring the kernel's N-tile choice).
 Each block's columns are consecutive usable physical columns; the physical
 span they cover — including the faulty columns interleaved between them —
 becomes one *window block*, and every window block pads to the common
-per-tensor stride ``window_block`` (= the max span).  The materialized
+per-tensor stride ``window_block`` (the max span rounded up to a multiple
+of 128 lanes).  The materialized
 window is the concatenation of these blocks, so logical block j's columns
 all live inside window slice ``[j*window_block, (j+1)*window_block)`` and
 the placed kernel streams exactly one window block per N-tile instead of
@@ -54,12 +55,23 @@ import numpy as np
 
 from repro.kernels.ops import N_BLOCK, largest_divisor
 
-PLACEMENT_FORMAT = "pud-placement-v2"
+PLACEMENT_FORMAT = "pud-placement-v3"
+_PLACEMENT_FORMAT_V2 = "pud-placement-v2"
 _PLACEMENT_FORMAT_V1 = "pud-placement-v1"
 
 # Logical columns per window block: the kernels' N tile, so one window
 # block feeds exactly one (full-size) output tile by construction.
 PLACE_BLOCK = N_BLOCK
+
+#: The window stride rounds up to a whole number of 128-column lane tiles:
+#: the chip's compiler tiles a block's last dimension only in multiples of
+#: 128.  Columns past a block's span are zero padding and never addressed.
+WINDOW_ALIGN = 128
+
+
+def aligned_window(span: int) -> int:
+    """Smallest multiple of :data:`WINDOW_ALIGN` >= ``span``."""
+    return -(-int(span) // WINDOW_ALIGN) * WINDOW_ALIGN
 
 
 class PlacementError(RuntimeError):
@@ -345,7 +357,7 @@ def plan_placement(
             slice_cols.append(cols.astype(np.int32))
             slice_starts.append(starts)
             slice_spans.append(spans)
-        window_block = int(max(s.max() for s in slice_spans))
+        window_block = aligned_window(max(s.max() for s in slice_spans))
 
         faulty, stuck = [], []
         for starts, spans in zip(slice_starts, slice_spans):
@@ -541,7 +553,7 @@ def _upgrade_v1_entry(phys: np.ndarray, region_start: np.ndarray,
         starts, spans = _slice_blocks(cols.astype(np.int64), block_cols)
         all_starts.append(starts)
         all_spans.append(spans)
-    window_block = int(max(s.max() for s in all_spans))
+    window_block = aligned_window(max(s.max() for s in all_spans))
 
     faulty, stuck = [], []
     for starts, spans, r0, f1, s1 in zip(all_starts, all_spans, r_starts,
@@ -568,18 +580,43 @@ def _upgrade_v1_entry(phys: np.ndarray, region_start: np.ndarray,
         stuck=(np.stack(stuck) if stacked else stuck[0]))
 
 
+def _realign_entry(phys: np.ndarray, block_cols: int, window_block: int,
+                   block_starts: np.ndarray, faulty: np.ndarray,
+                   stuck: np.ndarray) -> TensorPlacement:
+    """Load a v2 entry, whose stride was the raw max span, at the aligned
+    stride: each window block keeps its columns at the same in-block
+    offsets and gains zero padding at its end."""
+    stride = aligned_window(window_block)
+    if stride != window_block:
+        nb = block_starts.shape[-1]
+        lead = faulty.shape[:-1]
+
+        def pad(a):
+            blocks = a.reshape(lead + (nb, window_block))
+            blocks = np.pad(blocks, [(0, 0)] * (blocks.ndim - 1)
+                            + [(0, stride - window_block)])
+            return blocks.reshape(lead + (nb * stride,))
+
+        faulty, stuck = pad(faulty), pad(stuck)
+    return TensorPlacement(phys_cols=phys, block_cols=block_cols,
+                           window_block=stride, block_starts=block_starts,
+                           faulty=faulty, stuck=stuck)
+
+
 def load_placement_npz(path) -> Placement | None:
     """Read a Placement back; None on any corruption or format mismatch.
 
-    v1 archives (PR-2/PR-3 artifacts: one physical span per slice, no block
-    structure) load through ``_upgrade_v1_entry`` — old caches keep their
+    v1 archives (one physical span per slice, no block structure) load
+    through ``_upgrade_v1_entry``; v2 archives (block-aligned, stride not
+    lane-aligned) through ``_realign_entry`` — old caches keep their
     placements instead of re-planning.
     """
     try:
         with np.load(path, allow_pickle=False) as z:
             meta = json.loads(str(z["meta"]))
             fmt = meta.get("format")
-            if fmt not in (PLACEMENT_FORMAT, _PLACEMENT_FORMAT_V1):
+            if fmt not in (PLACEMENT_FORMAT, _PLACEMENT_FORMAT_V2,
+                           _PLACEMENT_FORMAT_V1):
                 return None
             entries = {}
             for i, name in enumerate(meta["names"]):
@@ -589,13 +626,10 @@ def load_placement_npz(path) -> Placement | None:
                         int(meta["region_sizes"][i]),
                         z[f"e{i}_faulty"], z[f"e{i}_stuck"])
                 else:
-                    entries[name] = TensorPlacement(
-                        phys_cols=z[f"e{i}_phys"],
-                        block_cols=int(meta["block_cols"][i]),
-                        window_block=int(meta["window_blocks"][i]),
-                        block_starts=z[f"e{i}_start"],
-                        faulty=z[f"e{i}_faulty"],
-                        stuck=z[f"e{i}_stuck"])
+                    entries[name] = _realign_entry(
+                        z[f"e{i}_phys"], int(meta["block_cols"][i]),
+                        int(meta["window_blocks"][i]), z[f"e{i}_start"],
+                        z[f"e{i}_faulty"], z[f"e{i}_stuck"])
             return Placement(
                 entries=entries,
                 grid_shape=tuple(meta["grid_shape"]),

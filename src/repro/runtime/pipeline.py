@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipelined_apply(
@@ -80,11 +79,11 @@ def pipelined_apply(
         return jax.lax.psum(acc, stage_axis)
 
     spec_params = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_params, P()),          # params stage-sharded, h replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, h)
 

@@ -220,8 +220,9 @@ def test_cli_requires_exactly_one_action(tmp_path):
 
 # ---------------------------------------------------------------------------
 # Placement serialization versions (the bit-packed refactor bumped the
-# placement format to v2 — block-aligned windows; PR-2/PR-3-era v1 archives
-# must keep loading through the upgrade path, unknown versions miss)
+# placement format to v2 — block-aligned windows — and the lane-aligned
+# stride to v3; v1 and v2 archives must keep loading through the upgrade
+# paths, unknown versions miss)
 # ---------------------------------------------------------------------------
 
 def _v1_placement_npz(path, plan, masks):
@@ -317,6 +318,65 @@ def test_v1_placement_archive_upgrades_on_load(tmp_path):
     meta = {"format": "pud-placement-v99", "names": []}
     np.savez(bad, meta=np.array(json.dumps(meta)))
     assert load_placement_npz(bad) is None
+
+
+def test_v2_placement_archive_realigns_on_load(tmp_path):
+    """A v2 archive stored the raw max span as the window stride; it loads
+    at the 128-aligned stride with every column at its old in-block
+    offset, so packs built from it equal packs from a fresh plan."""
+    import jax.numpy as jnp
+    from repro.pud.gemv import PUDGemvConfig, pud_linear
+    from repro.pud.packer import pack_model, packing_requests
+    from repro.pud.placement import (load_placement_npz, plan_placement,
+                                     save_placement_npz)
+    rng = np.random.default_rng(5)
+    masks = rng.random((4, 512)) < 0.05
+    params = {"m": {"wi": 0.05 * np.asarray(
+        rng.standard_normal((64, 96)), np.float32)},
+        "s": {"wi": 0.05 * np.asarray(
+            rng.standard_normal((2, 64, 96)), np.float32)}}
+    params = jax.tree_util.tree_map(jnp.asarray, params)
+    cfg = PUDGemvConfig(packable=("wi",))
+    plan = plan_placement(masks, packing_requests(
+        params, cfg, include_unembed=False))
+
+    path = tmp_path / "m0_v2.npz"
+    save_placement_npz(path, plan)
+    with np.load(path) as z:
+        arrays = dict(z)
+    meta = json.loads(str(arrays["meta"]))
+    raws = []
+    for i, name in enumerate(meta["names"]):
+        tp = plan.entries[name]
+        phys = np.atleast_2d(tp.phys_cols).astype(np.int64)
+        blocks = phys.reshape(phys.shape[0], -1, tp.block_cols)
+        raw = int((blocks[..., -1] - blocks[..., 0] + 1).max())
+        assert raw < tp.window_block           # the v2 stride was unaligned
+        for f in ("faulty", "stuck"):
+            a = arrays[f"e{i}_{f}"]
+            cut = a.reshape(a.shape[:-1] + (tp.n_blocks, tp.window_block))
+            arrays[f"e{i}_{f}"] = cut[..., :raw].reshape(
+                a.shape[:-1] + (tp.n_blocks * raw,))
+        raws.append(raw)
+    meta.update(format="pud-placement-v2", window_blocks=raws)
+    arrays["meta"] = np.array(json.dumps(meta))
+    with open(path, "wb") as f:
+        np.savez(f, **arrays)
+
+    got = load_placement_npz(path)
+    assert got is not None
+    for name, tp in plan.entries.items():
+        gtp = got.entries[name]
+        assert gtp.window_block == tp.window_block
+        np.testing.assert_array_equal(gtp.local_cols, tp.local_cols)
+        np.testing.assert_array_equal(gtp.faulty, tp.faulty)
+        np.testing.assert_array_equal(gtp.stuck, tp.stuck)
+    placed = pack_model(params, cfg, include_unembed=False, placement=got)
+    fresh = pack_model(params, cfg, include_unembed=False, placement=plan)
+    x = jnp.asarray(rng.standard_normal((3, 64)), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(pud_linear(x, placed.tensor("m/wi"))),
+        np.asarray(pud_linear(x, fresh.tensor("m/wi"))))
 
 
 def test_v1_placement_in_cache_reads_as_hit(warm, tmp_path):

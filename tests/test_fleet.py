@@ -7,7 +7,7 @@ import numpy as np
 from repro.core.calibrate import CalibrationConfig, identify_calibration
 from repro.core.ecr import fleet_ecr_summary, measure_ecr_fleet
 from repro.core.fleet import (FleetConfig, calibrate_fleet,
-                              fleet_calib_charges, ladder_tables,
+                              fleet_calib_charges, sense_tables,
                               load_or_calibrate, manufacture_fleet,
                               subarray_key)
 from repro.core.throughput import fleet_throughput
@@ -48,7 +48,7 @@ def test_grid_calibration_matches_per_subarray():
 def test_fused_kernel_matches_ref():
     """Fused Pallas calibration iteration vs kernels/ref.py, interpret mode."""
     ladder = CFG.ladder(P)
-    qsum, swing = ladder_tables(ladder, P)
+    level_v, level_sigma = sense_tables(ladder, P)
     key = jax.random.key(11)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     s, c = 64, 512
@@ -56,8 +56,7 @@ def test_fused_kernel_matches_ref():
     noise = jax.random.normal(k2, (s, c), jnp.float32)
     levels = jax.random.randint(k3, (c,), 0, ladder.n_levels, jnp.int32)
     offs = 0.03 * jax.random.normal(k4, (c,), jnp.float32)
-    args = (inputs, noise, levels, offs, P, ladder.n_fracs, qsum, swing,
-            0.0009, 5)
+    args = (inputs, noise, levels, offs, level_v, level_sigma, 0.0009, 5)
     got_l, got_b = calib_iter_fused(*args, interpret=True)
     want_l, want_b = calib_iter_ref(*args)
     np.testing.assert_array_equal(np.asarray(got_l), np.asarray(want_l))

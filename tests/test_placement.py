@@ -165,6 +165,29 @@ def test_block_aligned_window_blocks_over_p():
                                              4))))
 
 
+@pytest.mark.parametrize("p", [0.0, 0.036, 0.3])
+def test_window_block_is_lane_aligned(p):
+    """The placer rounds the window stride up to a multiple of 128 lanes
+    (the chip's compiler tiles a block's last axis only in such
+    multiples).  The pad at the end of each window block backs no physical
+    column: it is never addressed and never faulty."""
+    from repro.pud.placement import WINDOW_ALIGN
+    masks = _masks(g=4, c=2048, p=p, seed=5)
+    plan = plan_placement(masks, [PlacementRequest("m/wi", 768, 2),
+                                  PlacementRequest("u/w", 96, 0)])
+    for tp in plan.entries.values():
+        phys = np.atleast_2d(tp.phys_cols).astype(np.int64)
+        blocks = phys.reshape(phys.shape[0], -1, tp.block_cols)
+        raw = int((blocks[..., -1] - blocks[..., 0] + 1).max())
+        assert tp.window_block % WINDOW_ALIGN == 0
+        assert raw <= tp.window_block < raw + WINDOW_ALIGN
+        offs = np.asarray(tp.local_cols) % tp.window_block
+        assert (offs < raw).all()
+        faulty = np.atleast_2d(tp.faulty).reshape(
+            phys.shape[0], -1, tp.window_block)
+        assert not faulty[..., raw:].any()
+
+
 # ---------------------------------------------------------------------------
 # Packing + model integration
 # ---------------------------------------------------------------------------

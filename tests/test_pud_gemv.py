@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
+from repro.analysis.errors import ContractViolation
 from repro.kernels import ref
 from repro.kernels.ops import bitplane_gemv, quantize_activations
 from repro.pud.gemv import (PUDGemvConfig, PUDPerfModel, pack_linear,
@@ -28,8 +29,14 @@ def test_bitplane_gemv_matches_ref(b, k, n, wb, mode):
     w = jax.random.randint(kw, (k, n), -(1 << (wb - 1)), 1 << (wb - 1),
                            jnp.int32)
     planes = ref.pack_bitplanes(w, wb)
-    got = bitplane_gemv(x, planes, mode=mode)
     want = ref.bitplane_gemv_ref(x, planes)
+    if mode == "folded" and wb > 7:
+        # A folded 8-bit weight does not fit the int8 MXU operand.
+        with pytest.raises(ContractViolation, match="folded-weight-bits"):
+            bitplane_gemv(x, planes, mode=mode)
+        got = bitplane_gemv(x, planes, mode="planes")
+    else:
+        got = bitplane_gemv(x, planes, mode=mode)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # and the oracle equals the plain integer matmul
     direct = x.astype(jnp.int32) @ w
@@ -72,6 +79,29 @@ def test_quantize_activations_bounds():
     np.testing.assert_allclose(
         np.asarray(q.astype(jnp.float32) * scale), np.asarray(x),
         atol=float(scale.max()) * 0.51)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16, jnp.float32])
+def test_round_to_dtype_matches_a_cast(dtype):
+    """The explicit rounding the PUD path puts on its input and output
+    equals a round trip through ``dtype``, and leaves float32 alone."""
+    from repro.kernels.ops import round_to_dtype
+    x = jax.random.normal(jax.random.key(2), (4, 256), jnp.float32) * 3
+    got = round_to_dtype(x, dtype)
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(x.astype(dtype).astype(jnp.float32)))
+
+
+def test_pud_linear_output_is_at_input_precision():
+    """A bf16 input gives a float32 output already at bf16 precision, so
+    the model's cast back to bf16 changes nothing."""
+    x = jax.random.normal(jax.random.key(3), (3, 64), jnp.bfloat16)
+    w = jax.random.normal(jax.random.key(4), (64, 96), jnp.float32) * 0.1
+    y = pud_linear(x, pack_linear(w, 4))
+    assert y.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(y), np.asarray(y.astype(jnp.bfloat16).astype(jnp.float32)))
 
 
 # ---------------------------------------------------------------------------
