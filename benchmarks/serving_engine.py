@@ -293,7 +293,8 @@ def run(scale=None) -> list[dict]:
                               if hasattr(pm, "batch_speedup") else 1.0),
             "steps": sched["steps"],
             "slot_occupancy": sched["slot_occupancy"],
-            "wall_tok_s": sched["wall_tok_s"],
+            "step_tok_s": (sched["generated_tokens"]
+                           / sched["span_s"]["engine.step"]),
         })
     return rows, shard_scaling_rows(pm, flops_tok, spec)
 
@@ -332,7 +333,7 @@ def main(scale=None, argv=None) -> None:
               f"({r['batch_speedup']:5.2f}x), "
               f"{r['steps']:>3d} steps, "
               f"slot occupancy {r['slot_occupancy']:.1%}, "
-              f"{r['wall_tok_s']:6.1f} tok/s CPU wall{tag}")
+              f"{r['step_tok_s']:6.1f} tok/s CPU wall{tag}")
     up_to_opt = [r["model_tok_s"] for r in rows if r["batch_size"]
                  <= max(r2["batch_size"] for r2 in rows if r2["is_optimum"])]
     mono = all(a < b for a, b in zip(up_to_opt, up_to_opt[1:]))

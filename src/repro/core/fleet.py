@@ -40,6 +40,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels.ops import calib_iter_fused
 from repro.kernels.ref import calib_iter_ref
 from repro.pud.physics import NEUTRAL, PhysicsParams
+from repro.runtime.tracing import span
 from .calibrate import CalibrationConfig, identify_calibration_fn
 from .offsets import (OffsetLadder, levels_to_charges, make_ladder,
                       neutral_level)
@@ -181,43 +182,57 @@ def calibrate_fleet(
     ``mesh``: shard the subarray axis over every mesh axis (G must divide
     the device count evenly); without one, the grid runs vmapped on the
     local device.  ``method="per_subarray"`` is the bit-exact oracle.
+
+    Spans (``runtime/tracing``): ``repro.calib.call`` around the call, and
+    inside it ``repro.calib.build`` (jit, trace, lower, compile or load
+    from the persistent cache) then ``repro.calib.run`` (the dispatch; the
+    result is not waited for).
     """
     if method not in METHODS:
         raise ValueError(f"method {method!r} not in {METHODS}")
     g, _ = sense_offsets.shape
     ladder = cfg.ladder(params)
+    with span("calib.call", method=method, subarrays=int(g)):
+        if method == "per_subarray":
+            def one(idx, offs):
+                return identify_calibration_fn(
+                    subarray_key(key, idx), offs, ladder, params, config)
+            levels = _build_and_run(jax.vmap(one), jnp.arange(g),
+                                    sense_offsets)
+            return FleetCalibration(levels, None, cfg, method)
 
-    if method == "per_subarray":
-        def one(idx, offs):
-            return identify_calibration_fn(
-                subarray_key(key, idx), offs, ladder, params, config)
-        levels = jax.jit(jax.vmap(one))(
-            jnp.arange(g), sense_offsets)
-        return FleetCalibration(levels, None, cfg, method)
+        run = _block_calibrate(ladder, params, config, method)
 
-    run = _block_calibrate(ladder, params, config, method)
+        if mesh is None or mesh.size == 1:
+            levels, hist = _build_and_run(run, key, sense_offsets)
+            return FleetCalibration(levels, hist, cfg, method)
 
-    if mesh is None or mesh.size == 1:
-        levels, hist = jax.jit(run)(key, sense_offsets)
+        if g % mesh.size != 0:
+            raise ValueError(
+                f"{g} subarrays not divisible over {mesh.size} devices")
+        axes = tuple(mesh.axis_names)
+        spec = P(axes)
+
+        def sharded(key_block, offs):
+            idx = jnp.int32(0)
+            for name in axes:
+                idx = idx * mesh.shape[name] + jax.lax.axis_index(name)
+            levels, hist = run(jax.random.fold_in(key_block[0], idx), offs)
+            return levels, jax.lax.pmean(hist, axes)
+
+        levels, hist = _build_and_run(jax.shard_map(
+            sharded, mesh=mesh, in_specs=(P(), spec), out_specs=(spec, P()),
+            check_vma=False), key[None], sense_offsets)
         return FleetCalibration(levels, hist, cfg, method)
 
-    if g % mesh.size != 0:
-        raise ValueError(
-            f"{g} subarrays not divisible over {mesh.size} devices")
-    axes = tuple(mesh.axis_names)
-    spec = P(axes)
 
-    def sharded(key_block, offs):
-        idx = jnp.int32(0)
-        for name in axes:
-            idx = idx * mesh.shape[name] + jax.lax.axis_index(name)
-        levels, hist = run(jax.random.fold_in(key_block[0], idx), offs)
-        return levels, jax.lax.pmean(hist, axes)
-
-    levels, hist = jax.jit(jax.shard_map(
-        sharded, mesh=mesh, in_specs=(P(), spec), out_specs=(spec, P()),
-        check_vma=False))(key[None], sense_offsets)
-    return FleetCalibration(levels, hist, cfg, method)
+def _build_and_run(fn, *args):
+    """``jax.jit(fn)(*args)``, with building the executable and dispatching
+    it timed as separate spans."""
+    with span("calib.build"):
+        exe = jax.jit(fn).lower(*args).compile()
+    with span("calib.run"):
+        return exe(*args)
 
 
 def recalibrate_subarrays(

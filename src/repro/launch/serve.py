@@ -565,11 +565,13 @@ def main(argv=None) -> int:
                                     requests, tokens, max_len)
         completions = engine.run(requests)
         sched = engine.scheduler_report()
+        step_s = sched["span_s"].get("engine.step", 0.0)
+        rate = sched["generated_tokens"] / step_s if step_s else 0.0
         print(f"  engine: {sched['completed']} requests, "
               f"{sched['generated_tokens']} tokens in {sched['steps']} steps "
               f"({sched['batch_size']} slots, "
               f"occupancy {sched['slot_occupancy']:.1%}, "
-              f"{sched['wall_tok_s']:.1f} tok/s {platform} wall)")
+              f"{rate:.1f} tok/s {platform} wall over its steps)")
         if args.chunk_prefill:
             print(f"    chunked prefill: {sched['prefill_chunks']} chunks "
                   f"of {engine.chunk_prefill} tokens "

@@ -12,6 +12,12 @@ Entry points (the Model protocol used by launch/ and configs/):
   * prefill(params, tokens)             -> (last-position logits, cache)
   * decode_step(params, cache, token, cur_len) -> (logits, cache)
 plus param_defs() / cache_defs() metadata for init, sharding and the dry-run.
+
+Compiled programs carry ``jax.named_scope`` regions, so a profiler trace
+can attribute device time by op name: ``embed``, ``attention`` (ln1, the
+attention call and its residual add), ``ffn`` (ln2, the mixer and its
+residual add) and ``unembed`` (final norm and logits).  What lies outside
+them is the layer scan's own cache plumbing and whatever the caller adds.
 """
 from __future__ import annotations
 
@@ -168,26 +174,48 @@ class TransformerLM:
             return moe_ffn(p, cfg.moe_config(), h_norm)
         return ffn(p, h_norm, cfg.activation), 0.0
 
+    def _mix_residual(self, kind, p, h):
+        """h + mixer(ln2(h)), in the ``ffn`` scope; returns (h, aux)."""
+        with jax.named_scope("ffn"):
+            hn = rmsnorm(p["ln2"], h,
+                         zero_centered=self.cfg.zero_centered_norm)
+            f, aux = self._mix(kind, p["mixer"], hn)
+            return h + f, aux
+
     def _layer_full(self, kind, p, h, positions):
         cfg = self.cfg
-        hn = rmsnorm(p["ln1"], h, zero_centered=cfg.zero_centered_norm)
-        if cfg.attention == "mla":
-            a, kv = attn_mod.mla_attention(p["attn"], cfg.mla_config(), hn,
-                                           positions)
-        else:
-            a, kv = attn_mod.gqa_attention(p["attn"], cfg.attn_config(), hn,
-                                           positions)
-        h = h + a
-        hn = rmsnorm(p["ln2"], h, zero_centered=cfg.zero_centered_norm)
-        f, aux = self._mix(kind, p["mixer"], hn)
-        return h + f, kv, aux
+        with jax.named_scope("attention"):
+            hn = rmsnorm(p["ln1"], h, zero_centered=cfg.zero_centered_norm)
+            if cfg.attention == "mla":
+                a, kv = attn_mod.mla_attention(p["attn"], cfg.mla_config(),
+                                               hn, positions)
+            else:
+                a, kv = attn_mod.gqa_attention(p["attn"], cfg.attn_config(),
+                                               hn, positions)
+            h = h + a
+        h, aux = self._mix_residual(kind, p, h)
+        return h, kv, aux
 
     def _embed_tokens(self, params, tokens):
         cfg = self.cfg
-        h = embed(params["embed"], tokens).astype(cfg.dtype)
-        if cfg.embed_scale:
-            h = h * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
-        return h
+        with jax.named_scope("embed"):
+            h = embed(params["embed"], tokens).astype(cfg.dtype)
+            if cfg.embed_scale:
+                h = h * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
+            return h
+
+    def _unembed_last(self, params, h, last_idx=None):
+        """Logits [B, V] of row ``last_idx`` (default: the last) of the
+        final-normed ``h``, in the ``unembed`` scope."""
+        with jax.named_scope("unembed"):
+            h = rmsnorm(params["final_norm"], h,
+                        zero_centered=self.cfg.zero_centered_norm)
+            if last_idx is None:
+                h_last = h[:, -1]
+            else:
+                h_last = jax.lax.dynamic_slice_in_dim(
+                    h, last_idx, 1, axis=1)[:, 0]
+            return logits_last(params["unembed"], h_last)
 
     def _backbone(self, params, h, positions, collect_cache=False):
         """Run all layer groups. Returns (h, caches, aux_total)."""
@@ -258,8 +286,6 @@ class TransformerLM:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
         h = self._embed_tokens(params, tokens)
         h, raw, _ = self._backbone(params, h, positions, collect_cache=True)
-        h = rmsnorm(params["final_norm"], h,
-                    zero_centered=cfg.zero_centered_norm)
         cache = {}
         for name, kv in raw.items():
             if cfg.attention == "mla":
@@ -271,12 +297,7 @@ class TransformerLM:
                 k, v = kv
                 pad = ((0, 0), (0, 0), (0, max_len - s), (0, 0), (0, 0))
                 cache[name] = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}
-        if last_idx is None:
-            h_last = h[:, -1]
-        else:
-            h_last = jax.lax.dynamic_slice_in_dim(
-                h, last_idx, 1, axis=1)[:, 0]
-        return logits_last(params["unembed"], h_last), cache
+        return self._unembed_last(params, h, last_idx), cache
 
     def prefill_chunk(self, params, tokens, cache, start, *, kv_len: int,
                       last_idx=None):
@@ -309,34 +330,26 @@ class TransformerLM:
 
             def body(h, xs, kind=kind):
                 lp, lcache = xs
-                hn = rmsnorm(lp["ln1"], h,
-                             zero_centered=cfg.zero_centered_norm)
-                if cfg.attention == "mla":
-                    a, ckv, kr = attn_mod.mla_prefill_chunk(
-                        lp["attn"], cfg.mla_config(), hn, lcache["ckv"],
-                        lcache["kr"], start, kv_len)
-                    upd = {"ckv": ckv, "kr": kr}
-                else:
-                    a, k, v = attn_mod.gqa_prefill_chunk(
-                        lp["attn"], cfg.attn_config(), hn, lcache["k"],
-                        lcache["v"], start, kv_len)
-                    upd = {"k": k, "v": v}
-                h = h + a
-                hn = rmsnorm(lp["ln2"], h,
-                             zero_centered=cfg.zero_centered_norm)
-                f, _ = self._mix(kind, lp["mixer"], hn)
-                return h + f, upd
+                with jax.named_scope("attention"):
+                    hn = rmsnorm(lp["ln1"], h,
+                                 zero_centered=cfg.zero_centered_norm)
+                    if cfg.attention == "mla":
+                        a, ckv, kr = attn_mod.mla_prefill_chunk(
+                            lp["attn"], cfg.mla_config(), hn, lcache["ckv"],
+                            lcache["kr"], start, kv_len)
+                        upd = {"ckv": ckv, "kr": kr}
+                    else:
+                        a, k, v = attn_mod.gqa_prefill_chunk(
+                            lp["attn"], cfg.attn_config(), hn, lcache["k"],
+                            lcache["v"], start, kv_len)
+                        upd = {"k": k, "v": v}
+                    h = h + a
+                h, _ = self._mix_residual(kind, lp, h)
+                return h, upd
 
             h, upd = jax.lax.scan(body, h, (params[name], cache[name]))
             new_cache[name] = upd
-        h = rmsnorm(params["final_norm"], h,
-                    zero_centered=cfg.zero_centered_norm)
-        if last_idx is None:
-            h_last = h[:, -1]
-        else:
-            h_last = jax.lax.dynamic_slice_in_dim(
-                h, last_idx, 1, axis=1)[:, 0]
-        return logits_last(params["unembed"], h_last), new_cache
+        return self._unembed_last(params, h, last_idx), new_cache
 
     def decode_step(self, params, cache, tokens, cur_len):
         """tokens: [B, 1]; cur_len: current cache fill — a scalar int, or a
@@ -353,26 +366,23 @@ class TransformerLM:
 
             def body(h, xs, kind=kind):
                 lp, lcache = xs
-                hn = rmsnorm(lp["ln1"], h,
-                             zero_centered=cfg.zero_centered_norm)
-                if cfg.attention == "mla":
-                    a, ckv, kr = attn_mod.mla_decode(
-                        lp["attn"], cfg.mla_config(), hn, lcache["ckv"],
-                        lcache["kr"], cur_len)
-                    upd = {"ckv": ckv, "kr": kr}
-                else:
-                    a, k, v = attn_mod.gqa_decode(
-                        lp["attn"], cfg.attn_config(), hn, lcache["k"],
-                        lcache["v"], cur_len)
-                    upd = {"k": k, "v": v}
-                h = h + a
-                hn = rmsnorm(lp["ln2"], h,
-                             zero_centered=cfg.zero_centered_norm)
-                f, _ = self._mix(kind, lp["mixer"], hn)
-                return h + f, upd
+                with jax.named_scope("attention"):
+                    hn = rmsnorm(lp["ln1"], h,
+                                 zero_centered=cfg.zero_centered_norm)
+                    if cfg.attention == "mla":
+                        a, ckv, kr = attn_mod.mla_decode(
+                            lp["attn"], cfg.mla_config(), hn, lcache["ckv"],
+                            lcache["kr"], cur_len)
+                        upd = {"ckv": ckv, "kr": kr}
+                    else:
+                        a, k, v = attn_mod.gqa_decode(
+                            lp["attn"], cfg.attn_config(), hn, lcache["k"],
+                            lcache["v"], cur_len)
+                        upd = {"k": k, "v": v}
+                    h = h + a
+                h, _ = self._mix_residual(kind, lp, h)
+                return h, upd
 
             h, upd = jax.lax.scan(body, h, (params[name], cache[name]))
             new_cache[name] = upd
-        h = rmsnorm(params["final_norm"], h,
-                    zero_centered=cfg.zero_centered_norm)
-        return logits_last(params["unembed"], h[:, -1]), new_cache
+        return self._unembed_last(params, h), new_cache

@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .prefix_cache import PrefixCache
+from .tracing import span
 from .watchdog import StepWatchdog
 
 DEFAULT_MAX_BATCH = 32
@@ -108,7 +109,13 @@ class Request:
 
 @dataclasses.dataclass
 class Completion:
-    """A finished request: generated tokens plus scheduling metadata."""
+    """A finished request: generated tokens plus scheduling metadata.
+
+    The ``*_t`` fields are ``time.perf_counter()`` readings: ``submit_t`` at
+    ``submit``, ``prefill_t`` when its own prefill began (its admission, on
+    a full prefix-cache hit; None when shed from the queue), ``token_t[k]``
+    when token ``k`` was read back to the host.
+    """
 
     request_id: int
     tokens: list[int]             # generated tokens (length = max_new_tokens)
@@ -118,6 +125,9 @@ class Completion:
     logits: np.ndarray | None = None   # [gen, V] when collect_logits
     slo_met: bool | None = None   # None when the request had no deadline
     shed: bool = False            # dropped by the SLO policy / shed_request
+    submit_t: float | None = None
+    prefill_t: float | None = None
+    token_t: list[float] = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -159,6 +169,8 @@ class _Slot:
     phase: str = "decode"         # "prefill" | "decode"
     pf: _PrefillState | None = None
     deadline_vms: float | None = None   # virtual-clock deadline
+    prefill_t: float | None = None      # perf_counter at its prefill's start
+    token_t: list[float] = dataclasses.field(default_factory=list)
 
 
 class ServingEngine:
@@ -192,6 +204,12 @@ class ServingEngine:
     ``slo``               ``SLOConfig``, or a float shorthand for
                           ``SLOConfig(default_deadline_ms=...)``; enables
                           EDF admission + shedding.
+
+    Tracing: the engine opens ``repro.engine.*`` spans (``runtime/tracing``)
+    around each step, prefill, insert, decode dispatch and decode readback;
+    under ``jax.profiler`` they land in the trace, and their seconds and
+    counts are always summed into ``scheduler_report()["span_s"]`` /
+    ``["span_n"]``.  ``lane`` (set by ``FleetServingEngine``) tags them.
     """
 
     def __init__(self, model, params, *, max_len: int,
@@ -250,7 +268,9 @@ class ServingEngine:
         self._completions: list[Completion] = []
         self._step_idx = 0
         self._active_slot_steps = 0              # sum of live slots per step
-        self._decode_wall_s = 0.0
+        self._submit_t: dict[int, float] = {}    # request id -> perf_counter
+        self._span_totals: dict[str, list] = {}  # span name -> [seconds, n]
+        self.lane: int | None = None             # fleet lane, tags spans
 
         # SLO virtual clock: deterministic modeled milliseconds, advanced by
         # one modeled step time per scheduling step.
@@ -273,6 +293,8 @@ class ServingEngine:
         self._chunk_traces = 0
         self._prefill_chunks = 0                 # chunk calls executed
         self._prefilled_tokens = 0               # kv rows actually computed
+        self._prompt_tokens = 0                  # real prompt tokens among them
+        self._prefill_calls = 0                  # whole-prefill + chunk calls
 
         # Step telemetry: every decode step is bracketed by a StepWatchdog
         # (EMA step time, straggler flags, optional hang callback) and
@@ -337,6 +359,11 @@ class ServingEngine:
                     return pm.step_seconds(fpt, self.batch_size) * 1e3
         return DEFAULT_STEP_MS
 
+    def _span(self, name: str, **attrs) -> span:
+        if self.lane is not None:
+            attrs["lane"] = self.lane
+        return span(name, self._span_totals, **attrs)
+
     def _bucket(self, s: int) -> int:
         """pow2 prompt-length bucket, clamped to the cache length."""
         return min(self.max_len, _next_pow2(max(1, s)))
@@ -398,6 +425,7 @@ class ServingEngine:
             deadline = self._slo.default_deadline_ms
         self._deadlines[request.request_id] = (
             None if deadline is None else self._vtime_ms + float(deadline))
+        self._submit_t[request.request_id] = time.perf_counter()
         self._queue.append(request)
 
     def submit_all(self, requests) -> None:
@@ -510,7 +538,8 @@ class ServingEngine:
             finished_step=self._step_idx,
             logits=logits,
             slo_met=False,
-            shed=True))
+            shed=True,
+            submit_t=self._submit_t.pop(req.request_id, None)))
         self._slo_stats["missed"] += 1
 
     def _admission_order(self) -> list[Request]:
@@ -585,9 +614,10 @@ class ServingEngine:
         if entry is not None and entry.n_tokens == s and \
                 entry.logits is not None:
             # full hit: the stored cache + logits replace prefill outright
+            t = time.perf_counter()
             cache1 = self._pad_cache1(entry.cache)
             self._start_decode(slot, req, cache1,
-                               np.asarray(entry.logits).reshape(-1))
+                               np.asarray(entry.logits).reshape(-1), t, t)
             return
 
         if self.chunk_prefill is not None:
@@ -613,29 +643,38 @@ class ServingEngine:
 
         # whole prefill: pow2-bucketed for chunk-capable models, legacy
         # exact-length otherwise (MoE / non-transformer protocols)
-        if self._bucketed:
-            sb = self._bucket(s)
-            padded = np.zeros((1, sb), np.int32)
-            padded[0, :s] = tokens_np
-            logits, cache1 = self._prefill_bucketed(
-                self.params, jnp.asarray(padded),
-                jnp.asarray(s - 1, jnp.int32), sb)
-            self._prefilled_tokens += sb
-        else:
-            tokens = jnp.asarray(tokens_np)[None, :]
-            logits, cache1 = self._prefill(self.params, tokens,
-                                           tokens.shape[1])
-            self._prefilled_tokens += s
-        logits_row = np.asarray(logits[0])
+        sb = self._bucket(s) if self._bucketed else s
+        with self._span("engine.prefill", request=req.request_id, prompt=s,
+                        bucket=sb) as sp:
+            if self._bucketed:
+                padded = np.zeros((1, sb), np.int32)
+                padded[0, :s] = tokens_np
+                logits, cache1 = self._prefill_bucketed(
+                    self.params, jnp.asarray(padded),
+                    jnp.asarray(s - 1, jnp.int32), sb)
+            else:
+                tokens = jnp.asarray(tokens_np)[None, :]
+                logits, cache1 = self._prefill(self.params, tokens,
+                                               tokens.shape[1])
+            logits_row = np.asarray(logits[0])
+        self._prefilled_tokens += sb
+        self._prompt_tokens += s
+        self._prefill_calls += 1
         self._prefix_insert(tokens_np, cache1, logits_row)
-        self._start_decode(slot, req, cache1, logits_row)
+        self._start_decode(slot, req, cache1, logits_row, sp.start, sp.end)
 
     def _start_decode(self, slot: int, req: Request, cache1,
-                      logits_row: np.ndarray) -> None:
-        """Install a completed prefill into a batch lane and begin decode."""
+                      logits_row: np.ndarray, prefill_t: float,
+                      token_t: float) -> None:
+        """Install a completed prefill into a batch lane and begin decode.
+
+        ``prefill_t``: when this request's prefill began (kept if its slot
+        already has one, from its first chunk); ``token_t``: when the
+        logits row of its first token reached the host."""
         if self._cache is None:
             self._cache = self._zero_cache_like(cache1)
-        self._cache = self._insert(self._cache, cache1, slot)
+        with self._span("engine.insert", request=req.request_id, slot=slot):
+            self._cache = self._insert(self._cache, cache1, slot)
         first = int(np.argmax(logits_row))
         st = self._slots[slot]
         if st is None:                 # whole-prefill / full-hit admission
@@ -645,6 +684,9 @@ class ServingEngine:
             self._slots[slot] = st
         st.phase = "decode"
         st.pf = None
+        if st.prefill_t is None:
+            st.prefill_t = prefill_t
+        st.token_t.append(token_t)
         st.generated.append(first)
         if self.collect_logits:
             st.logits.append(np.asarray(logits_row))
@@ -671,23 +713,32 @@ class ServingEngine:
             if budget is not None and budget - progressed < c:
                 continue               # zero-budget chunk: hold, no progress
             start = pf.pos
-            chunk_tokens = jnp.asarray(
-                pf.tokens[start:start + c][None, :])
             is_last = start + c >= pf.prompt_len
             last_local = (pf.prompt_len - 1 - start) if is_last else (c - 1)
-            logits, pf.cache = self._chunk(
-                self.params, pf.cache, chunk_tokens,
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(last_local, jnp.int32), c, pf.bucket)
+            real = min(c, pf.prompt_len - start)
+            with self._span("engine.prefill", request=st.request.request_id,
+                            prompt=real, chunk=c) as sp:
+                chunk_tokens = jnp.asarray(
+                    pf.tokens[start:start + c][None, :])
+                logits, pf.cache = self._chunk(
+                    self.params, pf.cache, chunk_tokens,
+                    jnp.asarray(start, jnp.int32),
+                    jnp.asarray(last_local, jnp.int32), c, pf.bucket)
+                if is_last:
+                    logits_row = np.asarray(logits[0])
+            if st.prefill_t is None:
+                st.prefill_t = sp.start
             pf.pos = start + c
             progressed += c
             self._prefill_chunks += 1
+            self._prefill_calls += 1
             self._prefilled_tokens += c
+            self._prompt_tokens += real
             if is_last:
                 tokens_np = pf.tokens[:pf.prompt_len]
-                logits_row = np.asarray(logits[0])
                 self._prefix_insert(tokens_np, pf.cache, logits_row)
-                self._start_decode(slot, st.request, pf.cache, logits_row)
+                self._start_decode(slot, st.request, pf.cache, logits_row,
+                                   sp.start, sp.end)
         return progressed
 
     # -- eviction / shedding -------------------------------------------------
@@ -710,7 +761,10 @@ class ServingEngine:
             finished_step=self._step_idx,
             logits=(np.stack(st.logits) if st.logits else None),
             slo_met=slo_met,
-            shed=shed))
+            shed=shed,
+            submit_t=self._submit_t.pop(st.request.request_id, None),
+            prefill_t=st.prefill_t,
+            token_t=st.token_t))
         self._slots[slot] = None
         self._lens[slot] = 0
 
@@ -777,55 +831,57 @@ class ServingEngine:
 
         Returns the requests that finished on this step.
         """
-        done_before = len(self._completions)
-        if self._staged_params is not None:
-            self.params = self._staged_params
-            self._staged_params = None
-            self._swap_steps.append(self._step_idx)
-            self._params_version += 1
-            if self._prefix_cache is not None:
-                self._prefix_invalidated_entries += \
-                    self._prefix_cache.invalidate()
-        self._shed_expired()
-        self._admit()
-        chunked = self._advance_chunks()
-        live = [i for i, s in enumerate(self._slots)
-                if s is not None and s.phase == "decode"]
-        if live:
-            self._active_slot_steps += len(live)
-            self.watchdog.start_step(self._step_idx)
-            t0 = time.time()
-            nxt, logits, self._cache = self._step(
-                self.params, self._cache, jnp.asarray(self._tokens),
-                jnp.asarray(self._lens))
-            nxt = np.asarray(nxt)
-            self._decode_wall_s += time.time() - t0
-            self.watchdog.end_step()
-            self._step_idx += 1
-            logits_np = np.asarray(logits) if self.collect_logits else None
-            for i in live:
-                st = self._slots[i]
-                st.generated.append(int(nxt[i, 0]))
-                if self.collect_logits:
-                    st.logits.append(logits_np[i])
-                self._tokens[i, 0] = nxt[i, 0]
-                self._lens[i] += 1
-        worked = bool(live) or chunked > 0 or \
-            len(self._completions) > done_before
-        if worked:
-            self._vtime_ms += self._step_ms
-        if live:
-            for i in live:
-                st = self._slots[i]
-                if st is not None and \
-                        len(st.generated) >= st.request.max_new_tokens:
-                    self._evict(i)
-        self._last_step_worked = worked
-        # beat after evictions so a supervisor reads end-of-step state
-        if worked and self.heartbeat is not None:
-            self.heartbeat.beat(self._step_idx, active=self.n_active,
-                                completed=len(self._completions))
-        return self._completions[done_before:]
+        with self._span("engine.step", step=self._step_idx):
+            done_before = len(self._completions)
+            if self._staged_params is not None:
+                self.params = self._staged_params
+                self._staged_params = None
+                self._swap_steps.append(self._step_idx)
+                self._params_version += 1
+                if self._prefix_cache is not None:
+                    self._prefix_invalidated_entries += \
+                        self._prefix_cache.invalidate()
+            self._shed_expired()
+            self._admit()
+            chunked = self._advance_chunks()
+            live = [i for i, s in enumerate(self._slots)
+                    if s is not None and s.phase == "decode"]
+            if live:
+                self._active_slot_steps += len(live)
+                with self._span("engine.decode.dispatch", live=len(live)) as d:
+                    self.watchdog.start_step(self._step_idx, now=d.start)
+                    nxt, logits, self._cache = self._step(
+                        self.params, self._cache, jnp.asarray(self._tokens),
+                        jnp.asarray(self._lens))
+                with self._span("engine.decode.readback") as rb:
+                    nxt = np.asarray(nxt)
+                self.watchdog.end_step(now=rb.end)
+                self._step_idx += 1
+                logits_np = np.asarray(logits) if self.collect_logits else None
+                for i in live:
+                    st = self._slots[i]
+                    st.generated.append(int(nxt[i, 0]))
+                    st.token_t.append(rb.end)
+                    if self.collect_logits:
+                        st.logits.append(logits_np[i])
+                    self._tokens[i, 0] = nxt[i, 0]
+                    self._lens[i] += 1
+            worked = bool(live) or chunked > 0 or \
+                len(self._completions) > done_before
+            if worked:
+                self._vtime_ms += self._step_ms
+            if live:
+                for i in live:
+                    st = self._slots[i]
+                    if st is not None and \
+                            len(st.generated) >= st.request.max_new_tokens:
+                        self._evict(i)
+            self._last_step_worked = worked
+            # beat after evictions so a supervisor reads end-of-step state
+            if worked and self.heartbeat is not None:
+                self.heartbeat.beat(self._step_idx, active=self.n_active,
+                                    completed=len(self._completions))
+            return self._completions[done_before:]
 
     def run(self, requests=None) -> list[Completion]:
         """Drain the queue (plus ``requests``, if given) to completion.
@@ -852,12 +908,18 @@ class ServingEngine:
     # -- reporting -----------------------------------------------------------
 
     def scheduler_report(self) -> dict:
-        """Scheduler counters: slot occupancy, steps, measured decode rate,
-        prefill trace/chunk counters, prefix-cache and SLO telemetry."""
+        """Scheduler counters: slot occupancy, steps, host seconds per span
+        (``span_s``, with counts in ``span_n``; ``decode_wall_s`` is the
+        decode dispatch plus its readback), prefill counters (``prompt_tokens``
+        real prompt tokens among the ``prefilled_tokens`` rows computed, in
+        ``prefill_calls`` calls), prefix-cache and SLO telemetry."""
         steps = self._step_idx
         gen_tokens = sum(len(c.tokens) for c in self._completions)
         occ = (self._active_slot_steps / (steps * self.batch_size)
                if steps else 0.0)
+        span_s = {k: v[0] for k, v in self._span_totals.items()}
+        decode_wall_s = (span_s.get("engine.decode.dispatch", 0.0)
+                         + span_s.get("engine.decode.readback", 0.0))
         rep = {
             "batch_size": self.batch_size,
             "steps": steps,
@@ -866,9 +928,7 @@ class ServingEngine:
             "active": self.n_active,
             "generated_tokens": gen_tokens,
             "slot_occupancy": occ,
-            "decode_wall_s": self._decode_wall_s,
-            "wall_tok_s": (gen_tokens / self._decode_wall_s
-                           if self._decode_wall_s else 0.0),
+            "decode_wall_s": decode_wall_s,
             "stragglers": len(self.watchdog.stragglers),
             "step_ema_s": self.watchdog.ema_s,
             "hangs": self._hangs,
@@ -878,6 +938,10 @@ class ServingEngine:
             "chunk_traces": self._chunk_traces,
             "prefill_chunks": self._prefill_chunks,
             "prefilled_tokens": self._prefilled_tokens,
+            "prompt_tokens": self._prompt_tokens,
+            "prefill_calls": self._prefill_calls,
+            "span_s": span_s,
+            "span_n": {k: v[1] for k, v in self._span_totals.items()},
         }
         if self._prefix_cache is not None:
             pc = self._prefix_cache.stats()
@@ -894,6 +958,15 @@ class ServingEngine:
             rep.update(self.session.perf_report(
                 flops_per_token, batch_size=self.batch_size))
         return rep
+
+
+def _merge(dicts) -> dict:
+    """Per-key sums of numeric dicts."""
+    out: dict = {}
+    for d in dicts:
+        for k, v in d.items():
+            out[k] = out.get(k, 0) + v
+    return out
 
 
 class FleetServingEngine:
@@ -937,6 +1010,8 @@ class FleetServingEngine:
             ServingEngine(model, p, session=s, max_len=max_len,
                           batch_size=batch_size, **kw)
             for p, s in zip(lane_params, sessions)]
+        for i, lane in enumerate(self.lanes):
+            lane.lane = i
         self._next_lane = 0
 
     @property
@@ -998,18 +1073,28 @@ class FleetServingEngine:
     # -- reporting -----------------------------------------------------------
 
     def scheduler_report(self) -> dict:
-        """Fleet-merged counters plus the per-lane reports."""
+        """Fleet-merged counters plus the per-lane reports; span seconds
+        and counts are summed over lanes, which step one after another."""
         reps = [lane.scheduler_report() for lane in self.lanes]
+
+        def summed(key):
+            return sum(r[key] for r in reps)
+
         rep = {
             "n_lanes": len(self.lanes),
             "batch_size": self.batch_size,
             "steps": max(r["steps"] for r in reps),
-            "completed": sum(r["completed"] for r in reps),
-            "pending": sum(r["pending"] for r in reps),
-            "active": sum(r["active"] for r in reps),
-            "generated_tokens": sum(r["generated_tokens"] for r in reps),
-            "slot_occupancy": (sum(r["slot_occupancy"] for r in reps)
-                               / len(reps)),
+            "completed": summed("completed"),
+            "pending": summed("pending"),
+            "active": summed("active"),
+            "generated_tokens": summed("generated_tokens"),
+            "slot_occupancy": summed("slot_occupancy") / len(reps),
+            "prefilled_tokens": summed("prefilled_tokens"),
+            "prompt_tokens": summed("prompt_tokens"),
+            "prefill_calls": summed("prefill_calls"),
+            "decode_wall_s": summed("decode_wall_s"),
+            "span_s": _merge(r["span_s"] for r in reps),
+            "span_n": _merge(r["span_n"] for r in reps),
             "lanes": reps,
         }
         pcs = [r["prefix_cache"] for r in reps if "prefix_cache" in r]

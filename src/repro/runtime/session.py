@@ -263,7 +263,7 @@ class PUDSession:
         """
         if self._state is not None and not force:
             return self._state
-        t0 = time.time()
+        t0 = time.perf_counter()
         levels, ecr, masks, hit = load_or_calibrate(
             self.cache if self.cache is not None else _NullCache(),
             self.device_id, self.key, self.fleet_cfg, self.physics,
@@ -271,7 +271,7 @@ class PUDSession:
             n_trials_ecr=self.n_trials_ecr)
         self._state = CalibrationState(
             levels=levels, ecr=ecr, masks=masks,
-            cache_hit=bool(hit), wall_s=time.time() - t0)
+            cache_hit=bool(hit), wall_s=time.perf_counter() - t0)
         return self._state
 
     def baseline_ecr(self, n_trials: int | None = None) -> float:
@@ -334,7 +334,7 @@ class PUDSession:
                 "recalibrate_subarrays requires calibrate() first")
         from repro.core.ecr import measure_ecr_fleet
         from repro.core.fleet import fleet_calib_charges, recalibrate_subarrays
-        t0 = time.time()
+        t0 = time.perf_counter()
         idx = sorted(int(s) for s in subarrays)
         offs = jnp.asarray(sense_offsets)
         sub_levels = recalibrate_subarrays(
@@ -354,7 +354,7 @@ class PUDSession:
         self._state = CalibrationState(
             levels=jnp.asarray(levels), ecr=jnp.asarray(ecr),
             masks=jnp.asarray(masks), cache_hit=False,
-            wall_s=time.time() - t0)
+            wall_s=time.perf_counter() - t0)
         if self.cache is not None:
             self.cache.save(
                 self.device_id, self.fleet_cfg, self.physics, levels,

@@ -48,16 +48,18 @@ class StepWatchdog:
 
     # -- step bracketing -------------------------------------------------------
 
-    def start_step(self, step: int) -> None:
+    def start_step(self, step: int, now: float | None = None) -> None:
+        """``now``: the caller's own ``time.perf_counter()`` reading of the
+        step's start, so one clock read serves both."""
         self._step_idx = step
-        self._step_start = time.monotonic()
+        self._step_start = time.perf_counter() if now is None else now
         if self._monitor is None and self.on_hang is not None:
             self._monitor = threading.Thread(target=self._watch, daemon=True)
             self._monitor.start()
 
-    def end_step(self) -> dict:
+    def end_step(self, now: float | None = None) -> dict:
         assert self._step_start is not None, "end_step before start_step"
-        dt = time.monotonic() - self._step_start
+        dt = (time.perf_counter() if now is None else now) - self._step_start
         self._step_start = None
         is_straggler = self.ema_s is not None and dt > self.slow_factor * \
             self.ema_s
@@ -75,7 +77,7 @@ class StepWatchdog:
             start = self._step_start
             if start is None:
                 continue
-            waited = time.monotonic() - start
+            waited = time.perf_counter() - start
             if waited > self.hang_timeout_s:
                 self.on_hang(waited)
                 return
