@@ -243,9 +243,83 @@ def encoder_kv(p, cfg: AttnConfig, memory):
     return k, v
 
 
+def _decode_positions(x, cur_len):
+    """[B, 1] rope positions of one decode token per row: ``cur_len`` is a
+    scalar (every row at one position) or a [B] vector of per-slot
+    lengths (continuous batching)."""
+    lens = jnp.asarray(cur_len)
+    if lens.ndim == 1:
+        return lens[:, None]
+    return jnp.full((x.shape[0], 1), lens)
+
+
+def write_row(cache, new, cur_len, layer=None):
+    """Write one decode token's row into a KV cache at ``cur_len``.
+
+    new: [B, 1, ...].  cache: [B, Smax, ...], or with ``layer`` (a traced
+    index) the layer-stacked [L, B, Smax, ...] cache, written in place at
+    that layer.  A scalar ``cur_len`` writes every row at one position (a
+    dynamic-update-slice); a [B] vector writes row ``b`` at ``cur_len[b]``
+    (a scatter), and a row whose length is already ``Smax`` is dropped.
+    """
+    new = new.astype(cache.dtype)
+    lead = () if layer is None else (layer,)
+    lens = jnp.asarray(cur_len)
+    if lens.ndim == 1:
+        rows = jnp.arange(new.shape[0])
+        return cache.at[lead + (rows, lens)].set(new[:, 0], mode="drop")
+    start = lead + (0, lens) + (0,) * (new.ndim - 2)
+    return jax.lax.dynamic_update_slice(cache, new[(None,) * len(lead)],
+                                        start)
+
+
+def _length_mask(s, valid_len):
+    """Mask scores s [B, ..., Smax] to the first ``valid_len`` rows (a
+    scalar, or [B] per row)."""
+    smax = s.shape[-1]
+    valid = jnp.reshape(valid_len, (-1,) + (1,) * (s.ndim - 1))
+    return jnp.where(jnp.arange(smax) < valid, s, -jnp.inf)
+
+
+def gqa_decode_qkv(p, cfg: AttnConfig, x, cur_len):
+    """One decode token's projections. x: [B, 1, D].
+
+    Returns (q [B,1,H,Dh], k_new [B,1,KV,Dh], v_new [B,1,KV,Dh]): q and k
+    qk-normed (if the config says so) and rotated at ``cur_len``.
+    """
+    q = head_proj(p, "wq", x, cfg.n_heads, cfg.head_dim)
+    k_new = head_proj(p, "wk", x, cfg.n_kv_heads, cfg.head_dim)
+    v_new = head_proj(p, "wv", x, cfg.n_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        q = rmsnorm(p["q_norm"], q)
+        k_new = rmsnorm(p["k_norm"], k_new)
+    pos = _decode_positions(x, cur_len)
+    q = rope(q, pos, cfg.rope_theta)
+    k_new = rope(k_new, pos, cfg.rope_theta)
+    return q, k_new, v_new
+
+
+def gqa_decode_attend(p, q, cache_k, cache_v, valid_len):
+    """One query row per batch row against a KV cache, then ``wo``.
+
+    q: [B, 1, H, Dh]; cache_k/v: [B, Smax, KV, Dh]; rows at or past
+    ``valid_len`` (scalar or [B]) are masked.  Returns out [B, 1, D].
+    """
+    b = q.shape[0]
+    h, kvh, d = q.shape[2], cache_k.shape[2], q.shape[3]
+    g = h // kvh
+    qr = q.reshape(b, kvh, g, d)
+    s = jnp.einsum("bkgd,bpkd->bkgp", qr, cache_k,
+                   preferred_element_type=jnp.float32) * d ** -0.5
+    w = jax.nn.softmax(_length_mask(s, valid_len), axis=-1)
+    o = jnp.einsum("bkgp,bpkd->bkgd", w.astype(cache_v.dtype), cache_v)
+    return merge_proj(p, "wo", o.reshape(b, 1, h, d))
+
+
 def gqa_decode(p, cfg: AttnConfig, x, cache_k, cache_v, cur_len,
                cross: bool = False):
-    """One-token decode. x: [B, 1, D]; cache_k/v: [B, Smax, KV, D].
+    """One-token decode over one layer's cache. x: [B, 1, D];
+    cache_k/v: [B, Smax, KV, D].
 
     ``cur_len`` is the current cache fill: a scalar (all rows at the same
     position — the classic single-sequence/batched-prompt decode) or a [B]
@@ -257,50 +331,17 @@ def gqa_decode(p, cfg: AttnConfig, x, cache_k, cache_v, cur_len,
     Returns (out [B,1,D], new_cache_k, new_cache_v).
     For cross-attention the cache holds encoder K/V and is not updated.
     """
-    b, smax = cache_k.shape[0], cache_k.shape[1]
-    lens = jnp.asarray(cur_len)
-    per_slot = lens.ndim == 1
-    q = head_proj(p, "wq", x, cfg.n_heads, cfg.head_dim)
-    if not cross:
-        k_new = head_proj(p, "wk", x, cfg.n_kv_heads, cfg.head_dim)
-        v_new = head_proj(p, "wv", x, cfg.n_kv_heads, cfg.head_dim)
+    if cross:
+        q = head_proj(p, "wq", x, cfg.n_heads, cfg.head_dim)
         if cfg.qk_norm:
             q = rmsnorm(p["q_norm"], q)
-            k_new = rmsnorm(p["k_norm"], k_new)
-        pos = lens[:, None] if per_slot else jnp.full((b, 1), lens)
-        q = rope(q, pos, cfg.rope_theta)
-        k_new = rope(k_new, pos, cfg.rope_theta)
-        if per_slot:
-            rows = jnp.arange(b)
-            cache_k = cache_k.at[rows, lens].set(
-                k_new[:, 0].astype(cache_k.dtype), mode="drop")
-            cache_v = cache_v.at[rows, lens].set(
-                v_new[:, 0].astype(cache_v.dtype), mode="drop")
-        else:
-            cache_k = jax.lax.dynamic_update_slice_in_dim(
-                cache_k, k_new.astype(cache_k.dtype), cur_len, axis=1)
-            cache_v = jax.lax.dynamic_update_slice_in_dim(
-                cache_v, v_new.astype(cache_v.dtype), cur_len, axis=1)
-        valid_len = lens + 1
+        valid_len = cache_k.shape[1]
     else:
-        if cfg.qk_norm:
-            q = rmsnorm(p["q_norm"], q)
-        valid_len = jnp.full(lens.shape, smax)
-    h, kvh, d = q.shape[2], cache_k.shape[2], q.shape[3]
-    g = h // kvh
-    qr = q.reshape(b, kvh, g, d)
-    s = jnp.einsum("bkgd,bpkd->bkgp", qr, cache_k,
-                   preferred_element_type=jnp.float32) * d ** -0.5
-    if per_slot:
-        mask = jnp.arange(smax)[None, :] < valid_len[:, None]     # [B, Smax]
-        s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
-    else:
-        mask = jnp.arange(smax) < valid_len
-        s = jnp.where(mask[None, None, None, :], s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgp,bpkd->bkgd", w.astype(cache_v.dtype), cache_v)
-    o = o.reshape(b, 1, h, d)
-    out = merge_proj(p, "wo", o)
+        q, k_new, v_new = gqa_decode_qkv(p, cfg, x, cur_len)
+        cache_k = write_row(cache_k, k_new, cur_len)
+        cache_v = write_row(cache_v, v_new, cur_len)
+        valid_len = jnp.asarray(cur_len) + 1
+    out = gqa_decode_attend(p, q, cache_k, cache_v, valid_len)
     return out, cache_k, cache_v
 
 
@@ -415,36 +456,31 @@ def mla_prefill_chunk(p, cfg: MLAConfig, x, cache_ckv, cache_kr, start,
     return out, cache_ckv, cache_kr
 
 
-def mla_decode(p, cfg: MLAConfig, x, cache_ckv, cache_kr, cur_len):
-    """Absorbed-matmul MLA decode: attention runs in the latent space, so the
-    per-step cost is O(S * (R + dr)) instead of O(S * H * head_dim).
+def mla_decode_qkv(p, cfg: MLAConfig, x, cur_len):
+    """One decode token's MLA projections. x: [B, 1, D].
 
-    cache_ckv: [B, Smax, R]; cache_kr: [B, Smax, dr].
-    ``cur_len``: scalar or per-slot [B] vector, as in ``gqa_decode``.
+    Returns (q_nope [B,H,dn], q_rope [B,H,dr] rotated at ``cur_len``,
+    c_new [B,1,R] the normed latent, kr_new [B,1,dr] the rotated rope key).
     """
-    b, smax, r = cache_ckv.shape
-    lens = jnp.asarray(cur_len)
-    per_slot = lens.ndim == 1
     q = head_proj(p, "wq", x, cfg.n_heads, cfg.qk_dim)[:, 0]
     q_nope, q_rope = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
-    pos = lens[:, None] if per_slot else jnp.full((b, 1), lens)
+    pos = _decode_positions(x, cur_len)
     q_rope = rope(q_rope[:, None], pos, cfg.rope_theta)[:, 0]
-
     c_new = rmsnorm(p["kv_norm"], x @ p["wdkv"].astype(x.dtype))  # [B,1,R]
     kr_new = rope((x @ p["wkr"].astype(x.dtype))[:, :, None, :], pos,
                   cfg.rope_theta)[:, :, 0, :]
-    if per_slot:
-        rows = jnp.arange(b)
-        cache_ckv = cache_ckv.at[rows, lens].set(
-            c_new[:, 0].astype(cache_ckv.dtype), mode="drop")
-        cache_kr = cache_kr.at[rows, lens].set(
-            kr_new[:, 0].astype(cache_kr.dtype), mode="drop")
-    else:
-        cache_ckv = jax.lax.dynamic_update_slice_in_dim(
-            cache_ckv, c_new.astype(cache_ckv.dtype), cur_len, axis=1)
-        cache_kr = jax.lax.dynamic_update_slice_in_dim(
-            cache_kr, kr_new.astype(cache_kr.dtype), cur_len, axis=1)
+    return q_nope, q_rope, c_new, kr_new
 
+
+def mla_decode_attend(p, cfg: MLAConfig, q_nope, q_rope, cache_ckv,
+                      cache_kr, valid_len):
+    """Absorbed-matmul MLA attention over one layer's latent cache, then
+    ``wo``: the per-step cost is O(S * (R + dr)) instead of
+    O(S * H * head_dim).
+
+    cache_ckv: [B, Smax, R]; cache_kr: [B, Smax, dr]; rows at or past
+    ``valid_len`` (scalar or [B]) are masked.  Returns out [B, 1, D].
+    """
     # absorb W_uk into the query: scores in latent space.  bf16 inputs with
     # f32 accumulation (preferred_element_type) — an .astype(f32) on the
     # score made XLA hoist an f32 convert of the ENTIRE stacked cache out of
@@ -452,21 +488,16 @@ def mla_decode(p, cfg: MLAConfig, x, cache_ckv, cache_kr, cur_len):
     # decode_32k; EXPERIMENTS.md §Perf iteration 7).
     cache_ckv = hint(cache_ckv, "batch", "kv_seq", None)
     cache_kr = hint(cache_kr, "batch", "kv_seq", None)
-    q_lat = jnp.einsum("bhk,rhk->bhr", q_nope, p["wuk"].astype(x.dtype))
+    dtype = q_nope.dtype
+    q_lat = jnp.einsum("bhk,rhk->bhr", q_nope, p["wuk"].astype(dtype))
     s = (jnp.einsum("bhr,bpr->bhp", q_lat, cache_ckv,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bhk,bpk->bhp", q_rope, cache_kr,
                       preferred_element_type=jnp.float32))
     s = hint(s, "batch", None, "kv_seq")
     s = s * (cfg.qk_dim ** -0.5)
-    if per_slot:
-        mask = jnp.arange(smax)[None, :] < (lens + 1)[:, None]    # [B, Smax]
-        s = jnp.where(mask[:, None, :], s, -jnp.inf)
-    else:
-        mask = jnp.arange(smax) < lens + 1
-        s = jnp.where(mask[None, None, :], s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1)
+    w = jax.nn.softmax(_length_mask(s, valid_len), axis=-1)
     o_lat = jnp.einsum("bhp,bpr->bhr", w.astype(cache_ckv.dtype), cache_ckv)
-    o = jnp.einsum("bhr,rhk->bhk", o_lat, p["wuv"].astype(x.dtype))
-    out = merge_proj(p, "wo", o)[:, None]
-    return out, cache_ckv, cache_kr
+    o = jnp.einsum("bhr,rhk->bhk", o_lat, p["wuv"].astype(dtype))
+    return merge_proj(p, "wo", o)[:, None]
+

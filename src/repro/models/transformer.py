@@ -17,7 +17,8 @@ Compiled programs carry ``jax.named_scope`` regions, so a profiler trace
 can attribute device time by op name: ``embed``, ``attention`` (ln1, the
 attention call and its residual add), ``ffn`` (ln2, the mixer and its
 residual add) and ``unembed`` (final norm and logits).  What lies outside
-them is the layer scan's own cache plumbing and whatever the caller adds.
+them is the layer scan's own plumbing and whatever the caller adds; the
+decode step's KV row write and read sit inside ``attention``.
 """
 from __future__ import annotations
 
@@ -356,33 +357,57 @@ class TransformerLM:
         [B] int vector of per-slot lengths for continuous batching (each
         batch slot decodes at its own position; see runtime/engine.py).
 
+        Each group's stacked [L, B, max_len, ...] cache leaves ride the
+        layer scan's carry: layer ``li`` writes its new row in place at
+        ``[li, row, cur_len[row]]`` and attends over its own rows, read
+        from the carried buffer.  Through the scan's ``xs`` and ``ys``
+        instead, the cache could not alias the caller's donated buffer
+        (``xs`` are still read while ``ys`` are written): XLA would stack
+        a second whole cache and copy both back every step
+        (docs/serving.md).
+
         Returns (logits [B, V], new cache).
         """
         cfg = self.cfg
+        mla = cfg.attention == "mla"
+        valid_len = jnp.asarray(cur_len) + 1
         h = self._embed_tokens(params, tokens)
         new_cache = {}
         for gi, (kind, count) in enumerate(cfg.groups()):
             name = f"layers_{gi}_{kind}"
 
-            def body(h, xs, kind=kind):
-                lp, lcache = xs
+            def body(carry, xs, kind=kind):
+                h, kv = carry
+                lp, li = xs
                 with jax.named_scope("attention"):
                     hn = rmsnorm(lp["ln1"], h,
                                  zero_centered=cfg.zero_centered_norm)
-                    if cfg.attention == "mla":
-                        a, ckv, kr = attn_mod.mla_decode(
-                            lp["attn"], cfg.mla_config(), hn, lcache["ckv"],
-                            lcache["kr"], cur_len)
-                        upd = {"ckv": ckv, "kr": kr}
+                    if mla:
+                        q_nope, q_rope, c_new, kr_new = \
+                            attn_mod.mla_decode_qkv(lp["attn"],
+                                                    cfg.mla_config(), hn,
+                                                    cur_len)
+                        new = {"ckv": c_new, "kr": kr_new}
                     else:
-                        a, k, v = attn_mod.gqa_decode(
-                            lp["attn"], cfg.attn_config(), hn, lcache["k"],
-                            lcache["v"], cur_len)
-                        upd = {"k": k, "v": v}
+                        q, k_new, v_new = attn_mod.gqa_decode_qkv(
+                            lp["attn"], cfg.attn_config(), hn, cur_len)
+                        new = {"k": k_new, "v": v_new}
+                    kv = {n: attn_mod.write_row(c, new[n], cur_len, layer=li)
+                          for n, c in kv.items()}
+                    rows = {n: jax.lax.dynamic_index_in_dim(
+                                c, li, axis=0, keepdims=False)
+                            for n, c in kv.items()}
+                    if mla:
+                        a = attn_mod.mla_decode_attend(
+                            lp["attn"], cfg.mla_config(), q_nope, q_rope,
+                            rows["ckv"], rows["kr"], valid_len)
+                    else:
+                        a = attn_mod.gqa_decode_attend(
+                            lp["attn"], q, rows["k"], rows["v"], valid_len)
                     h = h + a
                 h, _ = self._mix_residual(kind, lp, h)
-                return h, upd
+                return (h, kv), None
 
-            h, upd = jax.lax.scan(body, h, (params[name], cache[name]))
-            new_cache[name] = upd
+            (h, new_cache[name]), _ = jax.lax.scan(
+                body, (h, cache[name]), (params[name], jnp.arange(count)))
         return self._unembed_last(params, h), new_cache
