@@ -36,7 +36,7 @@ from repro.pud.packed import (LAYOUT_BITPACK, LAYOUT_DENSE, PackedModel,
                               as_packed_tensor, load_packed_npz,
                               packed_bytes, save_packed_npz, to_bitpacked,
                               to_dense)
-from repro.pud.packer import (pack_for_serving, pack_model,
+from repro.pud.packer import (LayerSource, pack_for_serving, pack_model,
                               pack_model_sharded, packing_requests)
 from repro.pud.physics import PhysicsParams
 from repro.pud.placement import (Placement, PlacementError, PlacementRequest,
@@ -58,6 +58,7 @@ __all__ = [
     # sharded multi-device serving fleet
     "PUDFleetSession", "FleetServingEngine", "FleetDriftMonitor",
     "FleetPerfAggregate", "ShardedPackedTensor", "pack_model_sharded",
+    "LayerSource",
     "shard_column_slices", "check_shard_slices",
     # batched serving
     "ServingEngine", "Request", "Completion",

@@ -201,10 +201,14 @@ def pud_matmul_sharded(
     # model's next ops (residual, norms) split over the hidden axis and sum
     # partial reductions across devices, re-associating float sums the
     # one-device path adds in another order.
+    def gather(y):
+        with jax.named_scope("repro.tp.gather"):
+            return jax.lax.all_gather(y, axis)
+
     if placed:
         def body(xr, planes, scale, col_ids):
-            y = pud_matmul(xr, planes[0], scale[0], col_ids=col_ids[0], **kw)
-            return jax.lax.all_gather(y, axis)
+            return gather(pud_matmul(xr, planes[0], scale[0],
+                                     col_ids=col_ids[0], **kw))
 
         f = jax.shard_map(body, mesh=st.mesh,
                           in_specs=(P(), P(axis), P(axis), P(axis)),
@@ -212,8 +216,7 @@ def pud_matmul_sharded(
         y = f(x, st.planes, st.scale, st.col_ids)
     else:
         def body(xr, planes, scale):
-            return jax.lax.all_gather(
-                pud_matmul(xr, planes[0], scale[0], **kw), axis)
+            return gather(pud_matmul(xr, planes[0], scale[0], **kw))
 
         f = jax.shard_map(body, mesh=st.mesh,
                           in_specs=(P(), P(axis), P(axis)),

@@ -34,12 +34,16 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ref import pack_plane_words
+from repro.runtime.tracing import span
 
 from .gemv import (ATTN_PACKABLE, FFN_PACKABLE, PUDGemvConfig, pack_linear,
                    quantize_planes)
@@ -48,6 +52,34 @@ from .packed import (LAYOUT_BITPACK, PackedModel, PackedTensor,
 from .packed import packed_bytes  # noqa: F401  (legacy import location)
 from .placement import (Placement, PlacementRequest, TensorPlacement,
                         shard_column_slices)
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class LayerSource:
+    """A stacked packable leaf [L, K, N] given one layer at a time.
+
+    ``fn(l)`` returns layer ``l``'s [K, N] slice, on any device or on the
+    host.  ``pack_model_sharded`` packs such a leaf slice by slice, so the
+    whole stack never exists at once; ``PUDFleetSession`` keeps the source,
+    not the weights, to repack from after a recalibration.
+    """
+
+    shape: tuple[int, ...]
+    dtype: object
+    fn: Callable[[int], object]
+
+    @property
+    def ndim(self) -> int:
+        return len(self.shape)
+
+    def layer(self, layer: int):
+        return self.fn(layer)
+
+
+def _weight(x) -> bool:
+    """A leaf the packers read as a weight: a device array, a host
+    (numpy) array or a ``LayerSource``."""
+    return isinstance(x, (jax.Array, np.ndarray, LayerSource))
 
 
 def _match(packable: tuple[str, ...], key: str, path: tuple[str, ...]) -> bool:
@@ -75,6 +107,10 @@ def _canonical(key: str, path: tuple[str, ...], w: jax.Array):
     2-D, optionally with a stacked-layer axis in front.
     """
     if "attn" in path:
+        if isinstance(w, LayerSource):
+            raise TypeError(f"{'/'.join(path + (key,))}: an attention "
+                            "weight's head axes need the whole array, not "
+                            "a LayerSource")
         if key in ("wq", "wk", "wv"):
             if w.ndim == 3:       # [D, H, Dh]
                 return w.reshape(w.shape[0], -1)
@@ -175,8 +211,10 @@ def _pad_col_ids(ids: jax.Array, n_max: int, block_cols: int,
     return jnp.concatenate([ids, pad], axis=-1)
 
 
-def _normalize_placed_shard(pk: PackedTensor, bc: int, w_common: int,
-                            nb_max: int, n_max: int):
+@functools.partial(jax.jit, static_argnames=("wb_i", "bc", "w_common",
+                                             "nb_max", "n_max"))
+def _normalize_placed_shard(planes, scale, col_ids, wb_i: int, bc: int,
+                            w_common: int, nb_max: int, n_max: int):
     """Re-window one shard's placed pack onto the common padded geometry.
 
     The shard packed at its own ``window_block`` (wb_i, the max physical
@@ -186,21 +224,261 @@ def _normalize_placed_shard(pk: PackedTensor, bc: int, w_common: int,
     is a reshape/pad: block j's columns move from ``j*wb_i + t`` to
     ``j*w_common + t``.
     """
-    wb_i = pk.window_block
-    n_i = pk.col_ids.shape[-1]
-    nb_i = n_i // bc
-    planes = pk.planes                       # [L?, WB, Kw, nb_i*wb_i]
+    nb_i = col_ids.shape[-1] // bc
     pl = planes.reshape(planes.shape[:-1] + (nb_i, wb_i))
     pl = _pad_axis(_pad_axis(pl, -1, w_common), -2, nb_max)
     pl = pl.reshape(planes.shape[:-1] + (nb_max * w_common,))
-    j = pk.col_ids // wb_i
-    ids = (j * w_common + pk.col_ids - j * wb_i).astype(jnp.int32)
+    j = col_ids // wb_i
+    ids = (j * w_common + col_ids - j * wb_i).astype(jnp.int32)
     ids = _pad_col_ids(ids, n_max, bc, w_common)
-    scale = _pad_axis(pk.scale, -1, n_max, value=1.0)
+    scale = _pad_axis(scale, -1, n_max, value=1.0)
     return pl, scale, ids
 
 
-def pack_linear_sharded(w: jax.Array, n_shards: int, *, n_bits: int = 4,
+@dataclasses.dataclass
+class PackStats:
+    """Counters of one sharded pack (``PUDFleetSession.pack_report``).
+
+    ``layers``: stacked-layer indices packed; ``slices``: projection slices
+    quantised (one per projection and layer, one per unstacked
+    projection); ``device_bytes``: bytes of the packed model's arrays on
+    each device (packs and the replicated leaves); ``peak_weight_bytes``:
+    per device, the most bytes of unpacked projection weights the pack held
+    there at one time — the leaves given on a device for the whole pack,
+    plus the layer slice and the shard's columns in flight.
+    """
+
+    layers: int = 0
+    slices: int = 0
+    device_bytes: dict = dataclasses.field(default_factory=dict)
+    peak_weight_bytes: dict = dataclasses.field(default_factory=dict)
+    _held: dict = dataclasses.field(default_factory=dict, repr=False)
+
+    def hold(self, a, sign: int = 1) -> None:
+        """Count (``sign`` -1: uncount) a weight array where it lives."""
+        if not isinstance(a, jax.Array):
+            return                                  # host memory
+        for sh in a.addressable_shards:
+            d = str(sh.device)
+            self._held[d] = self._held.get(d, 0) + sign * sh.data.nbytes
+            self.peak_weight_bytes[d] = max(
+                self.peak_weight_bytes.get(d, 0), self._held[d])
+
+    def count_placed(self, tree) -> None:
+        """Add every device array of ``tree`` to ``device_bytes`` once."""
+        seen = set()
+        for a in jax.tree.leaves(tree):
+            if isinstance(a, jax.Array) and id(a) not in seen:
+                seen.add(id(a))
+                for sh in a.addressable_shards:
+                    d = str(sh.device)
+                    self.device_bytes[d] = (self.device_bytes.get(d, 0)
+                                            + sh.data.nbytes)
+
+    def report(self) -> dict:
+        return {"layers": self.layers, "slices": self.slices,
+                "device_bytes": dict(self.device_bytes),
+                "peak_weight_bytes": dict(self.peak_weight_bytes),
+                "max_weight_bytes": max(self.peak_weight_bytes.values(),
+                                        default=0)}
+
+
+@dataclasses.dataclass
+class _ShardPlan:
+    """How one projection splits, packs and pads over the model shards:
+    shard ``m`` owns logical columns ``spans[m]`` and (placed) packs them
+    on ``local[m]`` window positions; every shard pads to ``n_max``
+    columns and (placed) ``nb_max`` window blocks of ``w_common``."""
+
+    name: str
+    w: object                   # [K, N] / [L, K, N]: device, host or source
+    spans: list
+    block_cols: int
+    n_bits: int
+    tps: list | None            # per-shard TensorPlacement, None: logical
+    local: list | None          # per-shard tp.local_cols
+    n_max: int
+    w_common: int | None
+
+    @property
+    def n_layers(self) -> int | None:
+        return self.w.shape[0] if len(self.w.shape) == 3 else None
+
+    @property
+    def placed(self) -> bool:
+        return self.tps is not None
+
+    @property
+    def nb_max(self) -> int:
+        return self.n_max // self.block_cols
+
+    def layer(self, layer: int | None):
+        if layer is None:
+            return self.w
+        if isinstance(self.w, LayerSource):
+            return self.w.layer(layer)
+        return self.w[layer]
+
+
+def _shard_plan(w, n_shards: int, n_bits: int, placements, name: str
+                ) -> _ShardPlan:
+    spans, bc = shard_column_slices(w.shape[-1], n_shards)
+    tps = local = w_common = None
+    if placements is not None:
+        tps, local = [], []
+        for m, (lo, hi) in enumerate(spans):
+            tp = placements[m].entries[name] if hi > lo else None
+            if tp is not None and tp.block_cols != bc:
+                raise ValueError(
+                    f"shard {m} placement of {name!r} planned block_cols="
+                    f"{tp.block_cols}, the sharded split uses {bc} — plan "
+                    "per-shard placements with the forced block width")
+            tps.append(tp)
+            local.append(None if tp is None else np.asarray(tp.local_cols))
+        w_common = max(tp.window_block for tp in tps if tp is not None)
+    return _ShardPlan(name=name, w=w, spans=spans, block_cols=bc,
+                      n_bits=n_bits, tps=tps, local=local,
+                      n_max=max(hi - lo for lo, hi in spans),
+                      w_common=w_common)
+
+
+def _pack_shard_slice(plan: _ShardPlan, m: int, w2, layer: int | None,
+                      device, stats: PackStats):
+    """Shard ``m``'s columns of one [K, N] slice ``w2`` (host or any
+    device), quantised and bit-packed on ``device`` in the plan's common
+    padded geometry: (planes [WB, Kw, R], scale [Np], col_ids [Np] or
+    None)."""
+    lo, hi = plan.spans[m]
+    k = w2.shape[0]
+    if hi == lo:                 # zero-width shard: all padding
+        wb, kw = plan.n_bits, -(-k // 8)
+        if plan.placed:
+            ids = (jnp.arange(plan.n_max, dtype=jnp.int32)
+                   // plan.block_cols) * plan.w_common
+            planes = jnp.zeros((wb, kw, plan.nb_max * plan.w_common),
+                               jnp.uint8)
+        else:
+            ids = None
+            planes = jnp.zeros((wb, kw, plan.n_max), jnp.uint8)
+        return jax.device_put(
+            (planes, jnp.ones((plan.n_max,), jnp.float32), ids), device)
+    part = w2[:, lo:hi]
+    stats.hold(part)
+    wi = jax.device_put(part, device)
+    moved = not (isinstance(part, jax.Array)
+                 and part.devices() == wi.devices())   # else one buffer
+    if moved:
+        stats.hold(wi)
+    if plan.placed:
+        tp = plan.tps[m]
+        loc = plan.local[m] if layer is None else plan.local[m][layer]
+        idx = jax.device_put(np.asarray(loc, np.int32), device)
+        planes, scale = _placed_words(wi, idx, plan.n_bits, tp.region_size)
+        out = _normalize_placed_shard(
+            planes, scale, idx, wb_i=tp.window_block, bc=plan.block_cols,
+            w_common=plan.w_common, nb_max=plan.nb_max, n_max=plan.n_max)
+    else:
+        planes, scale = quantize_planes(wi, plan.n_bits, True)
+        out = (_pad_axis(planes, -1, plan.n_max),
+               _pad_axis(scale, -1, plan.n_max, value=1.0), None)
+    stats.hold(part, -1)
+    if moved:
+        stats.hold(wi, -1)
+    return out
+
+
+def _shard_devices(mesh, axis: str, n_shards: int) -> list:
+    """The device each model shard packs on: the first along ``axis``'s
+    other mesh axes (None off a mesh: the default device)."""
+    if mesh is None:
+        return [None] * n_shards
+    grid = np.moveaxis(np.asarray(mesh.devices),
+                       list(mesh.axis_names).index(axis), 0)
+    return [grid[m].reshape(-1)[0] for m in range(n_shards)]
+
+
+def _place_shards(pieces: list, pos: int, mesh, axis: str) -> jax.Array:
+    """Stack per-shard arrays on a new axis ``pos``; on a mesh, as one
+    array sharded over ``axis`` there, each shard's piece on the devices
+    that hold that shard (and replicated over the other mesh axes)."""
+    if mesh is None:
+        return jnp.stack(pieces, axis=pos)
+    shape = pieces[0].shape[:pos] + (len(pieces),) + pieces[0].shape[pos:]
+    spec = [None] * len(shape)
+    spec[pos] = axis
+    sharding = NamedSharding(mesh, P(*spec))
+    arrays = [jax.device_put(jnp.expand_dims(pieces[idx[pos].start], pos),
+                             dev)
+              for dev, idx in
+              sharding.addressable_devices_indices_map(shape).items()]
+    return jax.make_array_from_single_device_arrays(shape, sharding, arrays)
+
+
+def _assemble(plan: _ShardPlan, pieces: list, backend: str | None, mesh,
+              axis: str) -> ShardedPackedTensor:
+    """One projection's ``ShardedPackedTensor`` from its per-shard, per-
+    layer (planes, scale, col_ids) pieces."""
+    stacked = plan.n_layers is not None
+    lead = 1 if stacked else 0
+
+    def child(i):
+        per = [jnp.stack([p[i] for p in ps]) if stacked else ps[0][i]
+               for ps in pieces]
+        return _place_shards(per, lead, mesh, axis)
+
+    k = plan.w.shape[-2]
+    return ShardedPackedTensor(
+        planes=child(0), scale=child(1),
+        col_ids=child(2) if plan.placed else None,
+        shard_widths=tuple(hi - lo for lo, hi in plan.spans),
+        block_cols=plan.block_cols, backend=backend, layout=LAYOUT_BITPACK,
+        logical_k=k, window_block=plan.w_common, axis=axis, mesh=mesh)
+
+
+def _pack_plans(plans: list[_ShardPlan], n_shards: int, backend, mesh,
+                axis: str, stats: PackStats) -> dict:
+    """Quantise, bit-pack and place every plan, one layer at a time: layer
+    ``l`` of every stacked projection, then each unstacked projection.
+    Each shard's columns go to that shard's device before they are packed,
+    so no device holds more than the layer slice in flight."""
+    devices = _shard_devices(mesh, axis, n_shards)
+    pieces = {p.name: [[] for _ in range(n_shards)] for p in plans}
+    given = [p.w for p in plans if isinstance(p.w, jax.Array)]
+    for w in given:
+        stats.hold(w)
+
+    def pack_slice(plan, layer):
+        w2 = plan.layer(layer)
+        fresh = w2 is not plan.w
+        if fresh:
+            stats.hold(w2)
+        for m in range(n_shards):
+            pieces[plan.name][m].append(_pack_shard_slice(
+                plan, m, w2, layer, devices[m], stats))
+        if fresh:
+            stats.hold(w2, -1)
+        stats.slices += 1
+
+    n_layers = max((p.n_layers or 0) for p in plans) if plans else 0
+    with span("fleet.pack", shards=n_shards):
+        for layer in range(n_layers):
+            with span("fleet.pack.layer", layer=layer):
+                for plan in plans:
+                    if plan.n_layers is not None and layer < plan.n_layers:
+                        pack_slice(plan, layer)
+            stats.layers += 1
+        for plan in plans:
+            if plan.n_layers is None:
+                with span("fleet.pack.layer", tensor=plan.name):
+                    pack_slice(plan, None)
+        out = {p.name: _assemble(p, pieces.pop(p.name), backend, mesh, axis)
+               for p in plans}
+    for w in given:
+        stats.hold(w, -1)
+    return out
+
+
+def pack_linear_sharded(w, n_shards: int, *, n_bits: int = 4,
                         placements: list[Placement | None] | None = None,
                         name: str | None = None, backend: str | None = None,
                         mesh=None, axis: str = "model",
@@ -212,81 +490,20 @@ def pack_linear_sharded(w: jax.Array, n_shards: int, *, n_bits: int = 4,
     each shard's slice packs independently — with that shard's own
     ``Placement`` when ``placements`` is given (placed layout; entries are
     looked up under ``name``), logically otherwise — then all shards pad
-    to a common per-device shape and stack on the shard axis.
+    to a common per-device shape and stack on the shard axis.  ``w`` may
+    live on a device or the host, or be a ``LayerSource``.
     """
-    n = w.shape[-1]
-    spans, bc = shard_column_slices(n, n_shards)
-    widths = tuple(hi - lo for lo, hi in spans)
-    placed = placements is not None
-    lead = w.shape[:-2]                          # () or (L,)
-
-    packs: list[PackedTensor | None] = []
-    for m, (lo, hi) in enumerate(spans):
-        if hi == lo:
-            packs.append(None)
-            continue
-        wi = w[..., lo:hi]
-        if placed:
-            tp = placements[m].entries[name]
-            if tp.block_cols != bc:
-                raise ValueError(
-                    f"shard {m} placement of {name!r} planned block_cols="
-                    f"{tp.block_cols}, the sharded split uses {bc} — plan "
-                    "per-shard placements with the forced block width")
-            packs.append(_pack_placed(wi, n_bits, tp, backend))
-        else:
-            packs.append(_pack_stacked(wi, n_bits, backend))
-
-    live = [p for p in packs if p is not None]
-    ref = live[0]
-    logical_k = ref.logical_k
-    kw_words = ref.planes.shape[-2]
-    wb = ref.planes.shape[-3]
-    n_max = max(widths)
-
-    if placed:
-        w_common = max(p.window_block for p in live)
-        nb_max = n_max // bc
-        region = nb_max * w_common
-        norm = []
-        pad_ids = jnp.broadcast_to(
-            (jnp.arange(n_max, dtype=jnp.int32) // bc) * w_common,
-            lead + (n_max,))
-        for p in packs:
-            if p is None:
-                norm.append((jnp.zeros(lead + (wb, kw_words, region),
-                                       jnp.uint8),
-                             jnp.ones(lead + (n_max,), jnp.float32),
-                             pad_ids))
-            else:
-                norm.append(_normalize_placed_shard(p, bc, w_common,
-                                                    nb_max, n_max))
-        planes = jnp.stack([t[0] for t in norm], axis=-4)
-        scale = jnp.stack([t[1] for t in norm], axis=-2)
-        col_ids = jnp.stack([t[2] for t in norm], axis=-2)
-    else:
-        w_common = None
-        planes = jnp.stack(
-            [_pad_axis(p.planes, -1, n_max) if p is not None
-             else jnp.zeros(lead + (wb, kw_words, n_max), jnp.uint8)
-             for p in packs], axis=-4)
-        scale = jnp.stack(
-            [_pad_axis(p.scale, -1, n_max, value=1.0) if p is not None
-             else jnp.ones(lead + (n_max,), jnp.float32)
-             for p in packs], axis=-2)
-        col_ids = None
-
-    return ShardedPackedTensor(
-        planes=planes, scale=scale, col_ids=col_ids, shard_widths=widths,
-        block_cols=bc, backend=backend, layout=LAYOUT_BITPACK,
-        logical_k=logical_k, window_block=w_common, axis=axis, mesh=mesh)
+    plan = _shard_plan(w, n_shards, n_bits, placements, name)
+    return _pack_plans([plan], n_shards, backend, mesh, axis,
+                       PackStats())[name]
 
 
 def pack_model_sharded(params: dict, cfg: PUDGemvConfig = PUDGemvConfig(),
                        *, n_shards: int,
                        placements: list[Placement | None] | None = None,
                        include_unembed: bool = True, mesh=None,
-                       axis: str = "model") -> PackedModel:
+                       axis: str = "model",
+                       stats: PackStats | None = None) -> PackedModel:
     """Tensor-parallel ``pack_model``: every pack is a ShardedPackedTensor.
 
     ``placements`` gives one per-shard ``Placement`` (planned on that
@@ -295,14 +512,27 @@ def pack_model_sharded(params: dict, cfg: PUDGemvConfig = PUDGemvConfig(),
     returned tree drops fp weights from packed projections exactly like
     ``pack_model``, so the single-device model code serves it unchanged —
     ``pud_linear`` dispatches on the pack type.
+
+    Packable leaves may be device arrays, host (numpy) arrays or
+    ``LayerSource``s; they are packed one layer slice at a time
+    (``_pack_plans``).  With a ``mesh``, each shard's arrays are placed on
+    the devices of its ``axis`` index and every other leaf is placed
+    replicated over the mesh, so a lane's programs compile as one SPMD
+    program.  ``stats`` (a ``PackStats``) collects the pack's counters.
     """
+    stats = PackStats() if stats is None else stats
     packed_names: list[str] = []
     skipped: list[str] = []
+    plans: list[_ShardPlan] = []
+    rep = None if mesh is None else NamedSharding(mesh, P())
 
-    def one(w, name):
-        return pack_linear_sharded(
-            w, n_shards, n_bits=cfg.weight_bits, placements=placements,
-            name=name, backend=cfg.backend, mesh=mesh, axis=axis)
+    def plan(w, name):
+        plans.append(_shard_plan(w, n_shards, cfg.weight_bits, placements,
+                                 name))
+        packed_names.append(name)
+        return name                    # a placeholder, swapped below
+
+    unembed = []
 
     def walk(tree, path):
         if not isinstance(tree, dict):
@@ -313,23 +543,34 @@ def pack_model_sharded(params: dict, cfg: PUDGemvConfig = PUDGemvConfig(),
             if isinstance(sub, dict):
                 out[key] = walk(sub, p)
                 continue
-            if isinstance(sub, jax.Array) and _match(cfg.packable, key, path):
+            if include_unembed and p == ("unembed", "w"):
+                unembed.append(sub)            # packed last, as pack_model
+                continue
+            if _weight(sub) and _match(cfg.packable, key, path):
                 w = _canonical(key, path, sub)
                 if w is not None:
-                    name = "/".join(p)
-                    out[key + "_pud"] = one(w, name)
-                    packed_names.append(name)
+                    out[key + "_pud"] = plan(w, "/".join(p))
                     continue
                 skipped.append("/".join(p))
-            out[key] = sub
+            if isinstance(sub, LayerSource):
+                raise TypeError(f"{'/'.join(p)} is given by layer but is "
+                                "not packed: give it as an array")
+            out[key] = sub if rep is None else jax.device_put(sub, rep)
         return out
 
-    packed = walk(params, ())
-    if include_unembed and "unembed" in packed:
-        w = packed["unembed"].pop("w")
-        packed["unembed"]["w_pud"] = one(w, "unembed/w")
-        packed_names.append("unembed/w")
-    return PackedModel(params=packed,
+    tree = walk(params, ())
+    if unembed:
+        tree["unembed"]["w_pud"] = plan(unembed[0], "unembed/w")
+    packs = _pack_plans(plans, n_shards, cfg.backend, mesh, axis, stats)
+
+    def swap(t):
+        return {k: (swap(v) if isinstance(v, dict)
+                    else packs[v] if k.endswith("_pud") else v)
+                for k, v in t.items()}
+
+    tree = swap(tree)
+    stats.count_placed(tree)
+    return PackedModel(params=tree,
                        packed_names=tuple(packed_names),
                        skipped_names=tuple(skipped),
                        weight_bits=cfg.weight_bits,
@@ -363,8 +604,7 @@ def packing_requests(params: dict, cfg: PUDGemvConfig = PUDGemvConfig(),
             p = path + (key,)
             if isinstance(sub, dict):
                 walk(sub, p)
-            elif (isinstance(sub, jax.Array)
-                  and _match(cfg.packable, key, path)):
+            elif _weight(sub) and _match(cfg.packable, key, path):
                 w = _canonical(key, path, sub)
                 if w is None:
                     continue

@@ -67,6 +67,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from .prefix_cache import PrefixCache
 from .tracing import span
@@ -173,6 +174,16 @@ class _Slot:
     token_t: list[float] = dataclasses.field(default_factory=list)
 
 
+def _replicated_like(params) -> NamedSharding | None:
+    """Replicated over the mesh ``params`` are placed on, or None for
+    params on one device."""
+    leaves = jax.tree.leaves(params)
+    sh = getattr(leaves[0], "sharding", None) if leaves else None
+    if isinstance(sh, NamedSharding):
+        return NamedSharding(sh.mesh, PartitionSpec())
+    return None
+
+
 class ServingEngine:
     """Continuous-batching decode engine for one model + packed params.
 
@@ -204,6 +215,10 @@ class ServingEngine:
     ``slo``               ``SLOConfig``, or a float shorthand for
                           ``SLOConfig(default_deadline_ms=...)``; enables
                           EDF admission + shedding.
+
+    Params placed over a device mesh (a fleet lane's) get KV caches made
+    replicated over the same mesh, so every program of the lane compiles
+    as one SPMD program.
 
     Tracing: the engine opens ``repro.engine.*`` spans (``runtime/tracing``)
     around each step, prefill, insert, decode dispatch and decode readback;
@@ -262,6 +277,7 @@ class ServingEngine:
         self._queue: collections.deque[Request] = collections.deque()
         self._slots: list[_Slot | None] = [None] * self.batch_size
         self._cache = None                       # allocated on first admit
+        self._cache_sharding = _replicated_like(params)
         # host-side slot state, shipped to the device once per step
         self._tokens = np.zeros((self.batch_size, 1), np.int32)
         self._lens = np.zeros((self.batch_size,), np.int32)
@@ -453,13 +469,14 @@ class ServingEngine:
         """Full-batch cache pytree from a batch-1 prefill cache."""
         b = self.batch_size
         return jax.tree.map(
-            lambda c: jnp.zeros(c.shape[:1] + (b,) + c.shape[2:], c.dtype),
-            cache1)
+            lambda c: jnp.zeros(c.shape[:1] + (b,) + c.shape[2:], c.dtype,
+                                device=self._cache_sharding), cache1)
 
     def _zero_cache1(self):
         """Fresh batch-1 KV pytree for a chunked-prefill slot."""
         defs = self.model.cache_defs(1, self.max_len)
-        return jax.tree.map(lambda d: jnp.zeros(d.shape, d.dtype), defs)
+        return jax.tree.map(lambda d: jnp.zeros(
+            d.shape, d.dtype, device=self._cache_sharding), defs)
 
     @staticmethod
     def _trim_cache1(cache1, length: int):

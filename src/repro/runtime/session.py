@@ -44,7 +44,8 @@ from repro.pud.gemv import (ECR_BASELINE_B300, ECR_PUDTUNE_T210,
                             FleetPerfAggregate, FleetPerfModel, PUDGemvConfig,
                             PUDPerfModel, pud_linear)
 from repro.pud.packed import PackedModel, packed_bytes
-from repro.pud.packer import pack_model, pack_model_sharded, packing_requests
+from repro.pud.packer import (PackStats, pack_model, pack_model_sharded,
+                              packing_requests)
 from repro.pud.physics import PhysicsParams
 from repro.pud.placement import (Placement, PlacementError, plan_for_grid,
                                  requests_fingerprint, shard_column_slices)
@@ -742,6 +743,20 @@ class PUDSession:
         return self._packed is not None and self._packed.placed
 
 
+def _pack_source(params: dict, packed: dict) -> dict:
+    """What a fleet repacks from: ``params`` with every leaf the pack left
+    unpacked swapped for its placed copy in ``packed``, and the packable
+    leaves as given (host arrays and ``LayerSource``s stay off the
+    devices)."""
+    out = {}
+    for key, sub in params.items():
+        if isinstance(sub, dict):
+            out[key] = _pack_source(sub, packed.get(key, {}))
+        else:
+            out[key] = packed.get(key, sub)
+    return out
+
+
 class PUDFleetSession:
     """A mesh-shaped grid of ``PUDSession``s serving one sharded model.
 
@@ -776,7 +791,8 @@ class PUDFleetSession:
         self.n_model = len(sessions[0])
         self._packs: "list[PackedModel] | None" = None
         self._pack_cfg: PUDGemvConfig | None = None
-        self._pack_args = None          # (params, name, include_unembed)
+        self._pack_args = None          # (source, name, include_unembed)
+        self._pack_stats: PackStats | None = None
         self._shard_widths: tuple[int, ...] | None = None
         self._tuning_report: dict | None = None
 
@@ -866,6 +882,14 @@ class PUDFleetSession:
         lane falls back to the logical sharded layout — shards of one lane
         always share a layout, which the stacked-children representation
         requires.
+
+        Packable leaves may be given on the host (numpy) or layer by layer
+        (``pud.packer.LayerSource``) as well as on a device: the pack
+        quantises one layer slice at a time and puts each shard's arrays
+        on that shard's devices, and every other leaf replicated over the
+        mesh (``pack_model_sharded``).  The packable leaves as given, with
+        the placed replicated leaves, are what :meth:`repack_lane` packs
+        from; :meth:`pack_report` gives the pack's counters.
         """
         if cfg is None:
             cfg = PUDGemvConfig(backend=self.sessions[0][0].backend)
@@ -875,6 +899,7 @@ class PUDFleetSession:
         reqs = packing_requests(params, cfg, include_unembed)
         sliced, self._shard_widths = self._shard_requests(reqs)
         packs = []
+        stats = PackStats()
         for d in range(self.n_data):
             placements = [self._plan_shard(d, m, sliced[m], name)
                           for m in range(self.n_model)]
@@ -884,10 +909,21 @@ class PUDFleetSession:
             packs.append(pack_model_sharded(
                 params, cfg, n_shards=self.n_model, placements=placements,
                 include_unembed=include_unembed, mesh=self.mesh,
-                axis=self.axis))
-        self._packs, self._pack_cfg = packs, cfg
-        self._pack_args = (params, name, include_unembed)
+                axis=self.axis, stats=stats))
+        self._packs, self._pack_cfg, self._pack_stats = packs, cfg, stats
+        self._pack_args = (_pack_source(params, packs[0].params), name,
+                           include_unembed)
         return packs
+
+    def pack_report(self) -> dict | None:
+        """Counters of the last :meth:`pack` or :meth:`repack_lane`
+        (``pud.packer.PackStats``): layers and slices packed, bytes placed
+        on each device, and the most unpacked weight bytes held on one
+        device during the pack (``max_weight_bytes``)."""
+        if self._pack_stats is None:
+            return None
+        return dict(self._pack_stats.report(),
+                    shard_widths=self._shard_widths)
 
     def repack_lane(self, data_lane: int, *,
                     changed_model: int | None = None) -> PackedModel:
@@ -916,9 +952,11 @@ class PUDFleetSession:
         if any(placements[m] is None
                for m in range(self.n_model) if sliced[m]):
             placements = None
+        self._pack_stats = PackStats()
         pm = pack_model_sharded(
             params, cfg, n_shards=self.n_model, placements=placements,
-            include_unembed=include_unembed, mesh=self.mesh, axis=self.axis)
+            include_unembed=include_unembed, mesh=self.mesh, axis=self.axis,
+            stats=self._pack_stats)
         self._packs[data_lane] = pm
         return pm
 

@@ -62,39 +62,3 @@ def input_pspecs(specs: dict, mesh: Mesh) -> dict:
 def named(mesh: Mesh, spec_tree):
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))
-
-
-def sharded_pack_specs(st) -> dict:
-    """PartitionSpecs for a ``pud.packed.ShardedPackedTensor``'s children.
-
-    The stacked shard axis S maps onto the pack's mesh axis; every other
-    dimension replicates.  S sits at a fixed offset from the *end* of each
-    child (planes [..., S, WB, Kw, R], scale/col_ids [..., S, Np]), which
-    keeps the spec correct for both single and stacked-layer packs.
-    """
-    def spec(arr, s_from_end: int) -> P:
-        axes: list = [None] * arr.ndim
-        axes[arr.ndim - s_from_end] = st.axis
-        return P(*axes)
-
-    fields = [("planes", 4), ("scale", 2)]
-    if st.col_ids is not None:
-        fields.append(("col_ids", 2))
-    return {name: spec(getattr(st, name), off) for name, off in fields}
-
-
-def put_sharded_pack(st):
-    """device_put a sharded pack's children onto its mesh.
-
-    Dispatch (``kernels.ops.pud_matmul_sharded``) shards its inputs per
-    call; pre-placing the children with the matching ``NamedSharding``
-    makes every call start from device-resident shards instead of
-    re-scattering replicated host arrays.  A no-op numerically.
-    """
-    if st.mesh is None:
-        raise ValueError("sharded pack carries no mesh — build it through "
-                         "PUDFleetSession.pack / pack_model_sharded(mesh=...)")
-    specs = sharded_pack_specs(st)
-    kw = {k: jax.device_put(getattr(st, k), NamedSharding(st.mesh, v))
-          for k, v in specs.items()}
-    return st.replace(**kw)

@@ -108,3 +108,71 @@ def test_calib_iter_fused_compiles_for_v5e(one_chip, g):
     hlo = _compile(calib_iter_fused, *args, level_v=level_v,
                    level_sigma=level_sigma, threshold=0.0009)
     assert "tpu_custom_call" in hlo
+
+
+def test_fleet_decode_step_compiles_for_v5e_2x2(topo, monkeypatch):
+    """One decode step of a 1x4 tensor-parallel fleet lane at granite-8b's
+    widths (two of its 36 layers), as the streamed pack and the engine
+    place it: every packed projection sharded by columns over the "model"
+    axis at the placer's window, every other leaf and the KV cache
+    replicated.  The placed kernels run inside ``shard_map`` at a
+    shard's width, and each projection's shards are all-gathered."""
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+    import repro.kernels.bitplane_gemm as gemm
+    import repro.kernels.bitplane_gemv as gemv
+    from repro.models.transformer import LMConfig, TransformerLM
+    from repro.pud.packed import LAYOUT_BITPACK, ShardedPackedTensor
+    from repro.runtime.engine import ServingEngine
+
+    # the described chip is not the process's backend: lower natively
+    monkeypatch.setattr(gemm, "interpret_mode", lambda interpret=None: False)
+    monkeypatch.setattr(gemv, "interpret_mode", lambda interpret=None: False)
+    mesh = jax.make_mesh((1, 4), ("data", "model"), devices=topo.devices,
+                         axis_types=(AxisType.Auto,) * 2)
+    model = TransformerLM(LMConfig(
+        name="granite-fleet", n_layers=2, d_model=4096, n_heads=32,
+        n_kv_heads=8, d_ff=14336, vocab=49152, head_dim=128))
+
+    def sharded(shape, k, n):
+        lead = shape[:-2]
+        window_block, n_blocks = _placer_window_block(n // 4)
+
+        def spec(tail, dtype):
+            axes = (None,) * len(lead) + ("model",) + (None,) * len(tail)
+            return jax.ShapeDtypeStruct(lead + (4,) + tail, dtype,
+                                        sharding=NamedSharding(mesh, P(*axes)))
+        return ShardedPackedTensor(
+            planes=spec((WB, k // 8, n_blocks * window_block), jnp.uint8),
+            scale=spec((n // 4,), jnp.float32),
+            col_ids=spec((n // 4,), jnp.int32), shard_widths=(n // 4,) * 4,
+            block_cols=256, backend="pallas", layout=LAYOUT_BITPACK,
+            logical_k=k, window_block=window_block, axis="model", mesh=mesh)
+
+    rep = NamedSharding(mesh, P())
+
+    def place(tree, path=()):
+        out = {}
+        for key, leaf in tree.items():
+            if isinstance(leaf, dict):
+                out[key] = place(leaf, path + (key,))
+            elif key in ("wi", "wg", "wo") and "mixer" in path \
+                    or path == ("unembed",):
+                out[key + "_pud"] = sharded(leaf.shape, *leaf.shape[-2:])
+            else:
+                out[key] = jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                                sharding=rep)
+        return out
+
+    params = place(model.param_defs())
+    b, s = 16, 2048
+    eng = ServingEngine(model, params, max_len=s, batch_size=b)
+    cache = jax.tree.map(lambda d: jax.ShapeDtypeStruct(
+        d.shape, d.dtype, sharding=rep), model.cache_defs(b, s))
+    compiled = eng._step.lower(
+        params, cache, jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=rep)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "all-gather" in hlo
+    assert eng._cache_sharding == rep
+    assert compiled.memory_analysis().argument_size_in_bytes < 16e9
