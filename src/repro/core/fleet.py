@@ -20,6 +20,8 @@ call:
         jitted stages.
     With a ``mesh``, the subarray axis is ``shard_map``-ped over every mesh
     axis (launch/mesh.py meshes compose directly), one RNG stream per shard.
+    The jitted program is built once per configuration and run with each
+    call's key (``calib_build_report`` counts builds and memo hits).
   * ``fleet_calib_charges``    — levels -> per-subarray calibration-row
     charges for downstream ECR / arithmetic measurement.
 
@@ -30,6 +32,7 @@ table instead of recalibrating.
 from __future__ import annotations
 
 import dataclasses
+from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
@@ -183,56 +186,117 @@ def calibrate_fleet(
     the device count evenly); without one, the grid runs vmapped on the
     local device.  ``method="per_subarray"`` is the bit-exact oracle.
 
-    Spans (``runtime/tracing``): ``repro.calib.call`` around the call, and
-    inside it ``repro.calib.build`` (jit, trace, lower, compile or load
-    from the persistent cache) then ``repro.calib.run`` (the dispatch; the
-    result is not waited for).
+    The jitted program is built once per configuration and reused with
+    each call's key (``_build_and_run``).  Spans (``runtime/tracing``):
+    ``repro.calib.call`` around the call, and inside it
+    ``repro.calib.build`` then ``repro.calib.run``.
     """
     if method not in METHODS:
         raise ValueError(f"method {method!r} not in {METHODS}")
     g, _ = sense_offsets.shape
-    ladder = cfg.ladder(params)
+    if mesh is not None and mesh.size == 1:
+        mesh = None
     with span("calib.call", method=method, subarrays=int(g)):
         if method == "per_subarray":
-            def one(idx, offs):
-                return identify_calibration_fn(
-                    subarray_key(key, idx), offs, ladder, params, config)
-            levels = _build_and_run(jax.vmap(one), jnp.arange(g),
-                                    sense_offsets)
+            levels = _build_and_run(method, True, cfg, params, config, None,
+                                    key, jnp.arange(g), sense_offsets)
             return FleetCalibration(levels, None, cfg, method)
-
-        run = _block_calibrate(ladder, params, config, method)
-
-        if mesh is None or mesh.size == 1:
-            levels, hist = _build_and_run(run, key, sense_offsets)
-            return FleetCalibration(levels, hist, cfg, method)
-
-        if g % mesh.size != 0:
+        if mesh is not None and g % mesh.size != 0:
             raise ValueError(
                 f"{g} subarrays not divisible over {mesh.size} devices")
-        axes = tuple(mesh.axis_names)
-        spec = P(axes)
-
-        def sharded(key_block, offs):
-            idx = jnp.int32(0)
-            for name in axes:
-                idx = idx * mesh.shape[name] + jax.lax.axis_index(name)
-            levels, hist = run(jax.random.fold_in(key_block[0], idx), offs)
-            return levels, jax.lax.pmean(hist, axes)
-
-        levels, hist = _build_and_run(jax.shard_map(
-            sharded, mesh=mesh, in_specs=(P(), spec), out_specs=(spec, P()),
-            check_vma=False), key[None], sense_offsets)
+        levels, hist = _build_and_run(method, False, cfg, params, config,
+                                      mesh, key, sense_offsets)
         return FleetCalibration(levels, hist, cfg, method)
 
 
-def _build_and_run(fn, *args):
-    """``jax.jit(fn)(*args)``, with building the executable and dispatching
-    it timed as separate spans."""
-    with span("calib.build"):
-        exe = jax.jit(fn).lower(*args).compile()
+def _make_program(method: str, subset: bool, cfg: FleetConfig,
+                  params: PhysicsParams, config: CalibrationConfig,
+                  mesh: Mesh | None):
+    """The calibration program of one configuration, not yet jitted.
+
+    ``subset`` (always, for ``per_subarray``): f(key, idx [n], offsets
+    [n, C]) -> levels [n, C], row i on its own stream
+    ``subarray_key(key, idx[i])``.  Otherwise f(key, offsets [G, C]) ->
+    (levels, |bias| history) on one stream for the grid, or one per shard
+    with a ``mesh``.
+    """
+    ladder = cfg.ladder(params)
+    if method == "per_subarray":
+        def one(key, idx, offs):
+            return identify_calibration_fn(
+                subarray_key(key, idx), offs, ladder, params, config)
+        return jax.vmap(one, in_axes=(None, 0, 0))
+
+    run = _block_calibrate(ladder, params, config, method)
+    if subset:
+        def one(key, idx, offs):
+            levels, _ = run(subarray_key(key, idx), offs[None])
+            return levels[0]
+        return jax.vmap(one, in_axes=(None, 0, 0))
+    if mesh is None:
+        return run
+
+    axes = tuple(mesh.axis_names)
+    spec = P(axes)
+
+    def sharded(key_block, offs):
+        idx = jnp.int32(0)
+        for name in axes:
+            idx = idx * mesh.shape[name] + jax.lax.axis_index(name)
+        levels, hist = run(jax.random.fold_in(key_block[0], idx), offs)
+        return levels, jax.lax.pmean(hist, axes)
+
+    grid = jax.shard_map(sharded, mesh=mesh, in_specs=(P(), spec),
+                         out_specs=(spec, P()), check_vma=False)
+    return lambda key, offs: grid(key[None], offs)
+
+
+_MEMO_SIZE = 16
+_memo: OrderedDict = OrderedDict()     # memo key -> jitted program, LRU
+_report = {"builds": 0, "hits": 0}
+
+
+def calib_build_report() -> dict:
+    """``{"builds", "hits"}``: calibration programs built, and calls served
+    from a program the memo held, since the process started."""
+    return dict(_report)
+
+
+def _build_and_run(method: str, subset: bool, cfg: FleetConfig,
+                   params: PhysicsParams, config: CalibrationConfig,
+                   mesh: Mesh | None, *args):
+    """Run the configuration's jitted program (``_make_program``) on
+    ``args``, building it only when the memo does not hold it.
+
+    The memo key is everything the program closes over, the two
+    calibration kernels included, so a kernel swapped at run time gets a
+    program of its own.  Only the program is kept: every call runs all its
+    iterations on its own key.  Past ``_MEMO_SIZE`` configurations the
+    least recently used program is dropped.
+
+    Spans: ``repro.calib.build`` (``hit``: the memo held the program; on a
+    miss, jit, trace, lower and compile, or load from the persistent
+    cache) then ``repro.calib.run`` (the dispatch; the result is not waited
+    for).  On a hit, shapes or shardings new to the program compile in
+    ``calib.run``, through jit's own cache.
+    """
+    memo_key = (method, subset, cfg, params, config, mesh,
+                calib_iter_fused, calib_iter_ref)
+    prog = _memo.get(memo_key)
+    with span("calib.build", hit=prog is not None):
+        if prog is None:
+            prog = jax.jit(_make_program(method, subset, cfg, params, config,
+                                         mesh))
+            prog.lower(*args).compile()
+            _memo[memo_key] = prog
+            if len(_memo) > _MEMO_SIZE:
+                _memo.popitem(last=False)
+            _report["builds"] += 1
+        else:
+            _memo.move_to_end(memo_key)
+            _report["hits"] += 1
     with span("calib.run"):
-        return exe(*args)
+        return prog(*args)
 
 
 def recalibrate_subarrays(
@@ -256,7 +320,8 @@ def recalibrate_subarrays(
     a joint {3, 5} pass would.  (Block methods run per subarray here rather
     than sharing one iteration stream across the block like
     ``calibrate_fleet``; for a *partial* pass, batching-independence is the
-    contract that matters.)
+    contract that matters.)  The program comes from the same memo as
+    ``calibrate_fleet``'s.
 
     Returns refreshed levels ``[len(subarrays), C]`` in ascending-index
     order; merging them into the full table is the caller's job
@@ -266,20 +331,8 @@ def recalibrate_subarrays(
         raise ValueError(f"method {method!r} not in {METHODS}")
     idx = jnp.asarray(sorted(int(s) for s in subarrays), jnp.int32)
     offs = jnp.asarray(sense_offsets)[idx]
-    ladder = cfg.ladder(params)
-
-    if method == "per_subarray":
-        def one(g, o):
-            return identify_calibration_fn(
-                subarray_key(key, g), o, ladder, params, config)
-        return jax.jit(jax.vmap(one))(idx, offs)
-
-    run = _block_calibrate(ladder, params, config, method)
-
-    def one(g, o):
-        levels, _ = run(subarray_key(key, g), o[None])
-        return levels[0]
-    return jax.jit(jax.vmap(one))(idx, offs)
+    return _build_and_run(method, True, cfg, params, config, None,
+                          key, idx, offs)
 
 
 def fleet_calib_charges(

@@ -1,15 +1,22 @@
 """Fleet calibration engine: grid == per-subarray equivalence, fused Pallas
-kernel vs oracle, shard_map path, cache round-trip, fleet ECR/throughput."""
+kernel vs oracle, shard_map path, the program memo, cache round-trip, fleet
+ECR/throughput."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.core.calibrate import CalibrationConfig, identify_calibration
+import repro.core.fleet as fleet
+from repro.core.calibrate import (CalibrationConfig, identify_calibration,
+                                  identify_calibration_fn)
 from repro.core.ecr import fleet_ecr_summary, measure_ecr_fleet
-from repro.core.fleet import (FleetConfig, calibrate_fleet,
+from repro.core.fleet import (FleetConfig, _block_calibrate,
+                              calib_build_report, calibrate_fleet,
                               fleet_calib_charges, sense_tables,
                               load_or_calibrate, manufacture_fleet,
-                              subarray_key)
+                              recalibrate_subarrays, subarray_key)
 from repro.core.throughput import fleet_throughput
 from repro.kernels.majx import calib_iter_fused
 from repro.kernels.ref import calib_iter_ref
@@ -221,3 +228,177 @@ SHARD_PROG = """
 
 def test_fleet_calibration_shard_map(forced_devices):
     forced_devices(SHARD_PROG, marker="SHARD_OK", devices=4)
+
+
+# ---------------------------------------------------------------------------
+# Program memo: one jitted program per configuration, a new key each call.
+# ---------------------------------------------------------------------------
+
+MEMO_CFG = FleetConfig(n_channels=1, n_banks=1, n_subarrays=2, n_cols=256)
+MEMO_CAL = CalibrationConfig(n_iterations=3, n_samples=64)
+
+
+def _fresh(key, offs, cfg, params, config, method):
+    """What one fresh ``jax.jit`` of the unmemoised program computes:
+    (levels, |bias| history or None)."""
+    ladder = cfg.ladder(params)
+    if method == "per_subarray":
+        def one(g, o):
+            return identify_calibration_fn(
+                subarray_key(key, g), o, ladder, params, config)
+        return jax.jit(jax.vmap(one))(jnp.arange(offs.shape[0]), offs), None
+    return jax.jit(_block_calibrate(ladder, params, config, method))(key, offs)
+
+
+def _assert_same(cal, want):
+    levels, hist = want
+    np.testing.assert_array_equal(np.asarray(cal.levels), np.asarray(levels))
+    if hist is None:
+        assert cal.mean_abs_bias is None
+    else:
+        np.testing.assert_array_equal(np.asarray(cal.mean_abs_bias),
+                                      np.asarray(hist))
+
+
+@pytest.mark.parametrize("method", ["fused", "reference", "per_subarray"])
+def test_memo_hit_on_a_new_key_is_bit_identical(method):
+    offs = manufacture_fleet(jax.random.key(31), MEMO_CFG, P)
+    calibrate_fleet(jax.random.key(1), offs, MEMO_CFG, P, MEMO_CAL,
+                    method=method)
+    before = calib_build_report()
+    key = jax.random.key(2)
+    cal = calibrate_fleet(key, offs, MEMO_CFG, P, MEMO_CAL, method=method)
+    after = calib_build_report()
+    assert after == {"builds": before["builds"], "hits": before["hits"] + 1}
+    _assert_same(cal, _fresh(key, offs, MEMO_CFG, P, MEMO_CAL, method))
+
+
+CHANGES = {
+    "grid_shape": dict(cfg=dataclasses.replace(MEMO_CFG, n_cols=128)),
+    "frac_counts": dict(cfg=dataclasses.replace(MEMO_CFG,
+                                                frac_counts=(1, 1, 0))),
+    "calibration": dict(config=dataclasses.replace(MEMO_CAL, n_iterations=4)),
+    "physics": dict(params=dataclasses.replace(P, sigma_dynamic=0.002)),
+    "method": dict(method="fused"),
+}
+
+
+@pytest.mark.parametrize("change", sorted(CHANGES))
+def test_memo_misses_on_a_changed_configuration(change):
+    base = dict(cfg=MEMO_CFG, params=P, config=MEMO_CAL, method="reference")
+    key = jax.random.key(3)
+    offs = manufacture_fleet(key, MEMO_CFG, P)
+    fleet._memo.clear()
+    calibrate_fleet(key, offs, MEMO_CFG, P, MEMO_CAL, method="reference")
+    new = {**base, **CHANGES[change]}
+    offs2 = manufacture_fleet(key, new["cfg"], new["params"])
+    before = calib_build_report()
+    cal = calibrate_fleet(key, offs2, new["cfg"], new["params"],
+                          new["config"], method=new["method"])
+    assert calib_build_report()["builds"] == before["builds"] + 1
+    _assert_same(cal, _fresh(key, offs2, new["cfg"], new["params"],
+                             new["config"], new["method"]))
+
+
+def _levels_unchanged(inputs, noise, levels, offs, *a, **kw):
+    return levels, jnp.zeros(levels.shape, jnp.float32)
+
+
+def test_memo_misses_on_a_swapped_kernel(monkeypatch):
+    key = jax.random.key(4)
+    offs = manufacture_fleet(key, MEMO_CFG, P)
+    good = calibrate_fleet(key, offs, MEMO_CFG, P, MEMO_CAL, method="fused")
+    monkeypatch.setattr(fleet, "calib_iter_fused", _levels_unchanged)
+    before = calib_build_report()
+    bad = calibrate_fleet(key, offs, MEMO_CFG, P, MEMO_CAL, method="fused")
+    assert calib_build_report()["builds"] == before["builds"] + 1
+    from repro.core.offsets import neutral_level
+    assert (np.asarray(bad.levels) == neutral_level(MEMO_CFG.ladder(P))).all()
+    assert (np.asarray(bad.levels) != np.asarray(good.levels)).any()
+    monkeypatch.undo()
+    before = calib_build_report()
+    again = calibrate_fleet(key, offs, MEMO_CFG, P, MEMO_CAL, method="fused")
+    assert calib_build_report()["hits"] == before["hits"] + 1
+    _assert_same(again, (good.levels, good.mean_abs_bias))
+
+
+def test_memo_is_bounded_least_recently_used(monkeypatch):
+    monkeypatch.setattr(fleet, "_MEMO_SIZE", 2)
+    fleet._memo.clear()
+    key = jax.random.key(5)
+    offs = manufacture_fleet(key, MEMO_CFG, P)
+    cals = [dataclasses.replace(MEMO_CAL, n_iterations=n) for n in (1, 2, 3)]
+
+    def builds(cal):
+        before = calib_build_report()["builds"]
+        calibrate_fleet(key, offs, MEMO_CFG, P, cal, method="reference")
+        return calib_build_report()["builds"] - before
+
+    assert [builds(c) for c in cals] == [1, 1, 1]
+    assert len(fleet._memo) == 2
+    assert builds(cals[2]) == 0          # newest kept
+    assert builds(cals[0]) == 1          # oldest dropped
+
+
+@pytest.mark.parametrize("method", ["fused", "reference", "per_subarray"])
+def test_recalibrate_subarrays_rows_unchanged_by_the_memo(method):
+    offs = manufacture_fleet(jax.random.key(6), CFG, P)
+    subset = [1, 3]
+    recalibrate_subarrays(jax.random.key(7), offs, subset, CFG, P, MEMO_CAL,
+                          method=method)
+    before = calib_build_report()
+    key = jax.random.key(8)
+    rows = recalibrate_subarrays(key, offs, subset, CFG, P, MEMO_CAL,
+                                 method=method)
+    assert calib_build_report()["hits"] == before["hits"] + 1
+    # each row is that subarray calibrated alone on its own stream
+    ladder = CFG.ladder(P)
+    for i, g in enumerate(subset):
+        if method == "per_subarray":
+            want = identify_calibration_fn(subarray_key(key, g), offs[g],
+                                           ladder, P, MEMO_CAL)
+        else:
+            want = jax.jit(_block_calibrate(ladder, P, MEMO_CAL, method))(
+                subarray_key(key, g), offs[g][None])[0][0]
+        np.testing.assert_array_equal(np.asarray(rows[i]), np.asarray(want))
+
+
+MESH_MEMO_PROG = """
+    import jax, numpy as np
+    from repro.core.calibrate import CalibrationConfig
+    from repro.core.fleet import FleetConfig, calib_build_report, \\
+        calibrate_fleet, manufacture_fleet
+    import repro.core.fleet as fleet
+    from repro.launch.mesh import make_host_mesh
+    from repro.pud.physics import PhysicsParams
+
+    params = PhysicsParams()
+    cfg = FleetConfig(n_channels=1, n_banks=2, n_subarrays=2, n_cols=256)
+    cal = CalibrationConfig(n_iterations=3, n_samples=64)
+    offs = manufacture_fleet(jax.random.key(1), cfg, params)
+
+    def call(mesh, k):
+        b = calib_build_report()["builds"]
+        c = calibrate_fleet(jax.random.key(k), offs, cfg, params, cal,
+                            mesh=mesh, method="reference")
+        return np.asarray(c.levels), calib_build_report()["builds"] - b
+
+    meshes = [None, make_host_mesh(2, 2), make_host_mesh(4, 1)]
+    first = [call(m, 9) for m in meshes]
+    assert [b for _, b in first] == [1, 1, 1], first
+    again = [call(m, 9) for m in meshes]
+    assert [b for _, b in again] == [0, 0, 0], again
+    for (a, _), (b, _) in zip(first, again):
+        np.testing.assert_array_equal(a, b)
+    # a mesh's answer is its own: per-shard streams differ from the grid's
+    assert (first[0][0] != first[1][0]).any()
+    # and the memo served what a fresh build computes
+    fleet._memo.clear()
+    for m, (a, _) in zip(meshes, first):
+        np.testing.assert_array_equal(call(m, 9)[0], a)
+    print("MESH_MEMO_OK")
+"""
+
+
+def test_memo_misses_on_another_mesh(forced_devices):
+    forced_devices(MESH_MEMO_PROG, marker="MESH_MEMO_OK", devices=4)

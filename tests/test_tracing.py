@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.core.fleet as fleet
 from repro.api import (CalibrationConfig, FleetConfig, PhysicsParams,
                        Request, ServingEngine)
 from repro.configs import get
@@ -192,6 +193,7 @@ def test_calibrate_fleet_spans_and_levels(tmp_path):
     calib = CalibrationConfig(n_iterations=3, n_samples=32)
     key = jax.random.key(11)
     offs = manufacture_fleet(key, cfg, physics)
+    fleet._memo.clear()
     with jax.profiler.trace(str(tmp_path)):
         cal = calibrate_fleet(key, offs, cfg, physics, calib,
                               method="reference")
@@ -203,6 +205,14 @@ def test_calibrate_fleet_spans_and_levels(tmp_path):
     assert _parent(spans, spans[1]) is spans[0]
     assert _parent(spans, spans[2]) is spans[0]
     assert spans[1]["end"] <= spans[2]["start"]
+    assert not spans[1]["attrs"]["hit"]
+    # a second call with another key is served the program the first built
+    with jax.profiler.trace(str(tmp_path / "again")):
+        calibrate_fleet(jax.random.key(12), offs, cfg, physics, calib,
+                        method="reference").levels.block_until_ready()
+    again = _spans(str(tmp_path / "again"))
+    assert [s["name"] for s in again] == names
+    assert again[1]["attrs"]["hit"]
     # the split into build and run computes what one jitted call did
     run = _block_calibrate(cfg.ladder(physics), physics, calib, "reference")
     levels, hist = jax.jit(run)(key, offs)
